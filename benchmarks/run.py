@@ -127,6 +127,9 @@ def main() -> None:
     storage = [k for k in SE.ALL if which is None or k in which]
     if storage:
         rows += SE.run(storage)
+    if which is None or {"kernels", "serving"} & set(which):
+        from repro.compile_cache import enable_compile_cache
+        enable_compile_cache()
     if which is None or "kernels" in which:
         rows += bench_kernels_reference()
     if which is None or "serving" in which:

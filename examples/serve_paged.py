@@ -11,12 +11,14 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import init_params
 from repro.serving import Request, ServingEngine
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("qwen3-1.7b").smoke()
     params = init_params(jax.random.PRNGKey(0), cfg)
     eng = ServingEngine(cfg, params, hbm_zones=6, host_zones=64,

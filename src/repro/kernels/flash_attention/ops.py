@@ -1,9 +1,9 @@
 """Jit'd public wrapper for the flash attention kernel.
 
-On TPU this runs the Pallas kernel; everywhere else (CPU CI) it runs in
-interpret mode or falls back to the jnp reference.  The backward pass is a
-custom VJP that recomputes attention with the reference implementation —
-numerically exact, memory-light (flash-style recompute).
+On an accelerator this compiles the Pallas kernel; on the CPU backend it
+runs in interpret mode (see ``repro.kernels.default_interpret``).  The
+backward pass is a custom VJP that recomputes attention with the reference
+implementation — numerically exact, memory-light (flash-style recompute).
 """
 from __future__ import annotations
 
@@ -13,15 +13,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .. import default_interpret
 from .flash_attention import flash_attention_fwd
 from .ref import attention_ref
-
-
-def _is_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -29,7 +23,7 @@ def flash_attention(q, k, v, causal: bool = True,
                     window: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """q: [B, H, S, D]; k/v: [B, KV, S, D] -> [B, H, S, D]."""
-    interp = (not _is_tpu()) if interpret is None else interpret
+    interp = default_interpret() if interpret is None else interpret
     return flash_attention_fwd(q, k, v, causal=causal, window=window,
                                interpret=interp)
 

@@ -6,20 +6,14 @@ from typing import Optional
 
 import jax
 
+from .. import default_interpret
 from .paged_attention import paged_attention_decode
 from .ref import paged_attention_ref
-
-
-def _is_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                     interpret: Optional[bool] = None):
-    interp = (not _is_tpu()) if interpret is None else interpret
+    interp = default_interpret() if interpret is None else interpret
     return paged_attention_decode(q, k_pages, v_pages, block_tables,
                                   context_lens, interpret=interp)

@@ -6,18 +6,12 @@ from typing import Optional
 
 import jax
 
+from .. import default_interpret
 from .selective_scan import selective_scan
 from .ref import selective_scan_ref
 
 
-def _is_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def mamba_scan(dt, bx, c, a, interpret: Optional[bool] = None):
-    interp = (not _is_tpu()) if interpret is None else interpret
+    interp = default_interpret() if interpret is None else interpret
     return selective_scan(dt, bx, c, a, interpret=interp)

@@ -16,8 +16,9 @@ probe positions).  The hash family is shared across every implementation:
   kernel (``repro.kernels.bloom_probe``).
 
 The numpy fallback is the simulator default (no jax import required);
-``impl="jax"`` routes probes through the kernel package, and the
-cross-implementation agreement is asserted by ``tests/test_filters.py``.
+``impl="jax"`` runs the batched probe as one jitted call on the default
+JAX device (:func:`probe_pairs_device`), and the cross-implementation
+agreement is asserted by ``tests/test_filters.py``.
 """
 from __future__ import annotations
 
@@ -134,24 +135,35 @@ def probe_one_np(key: int, bits: np.ndarray, k_hashes: int) -> bool:
 
 
 # ----------------------------------------------------------------------
-# jax route (kernel package) — optional, bit-identical
+# jax route (the batched probe on the default JAX device) — bit-identical
 # ----------------------------------------------------------------------
 _HAVE_JAX: Optional[bool] = None
 
+# smallest padded sizes of the device probe: the pair count and the
+# filter-image length are rounded up to powers of two no smaller than
+# these, so a whole run compiles a handful of shapes however many
+# batches it probes
+MIN_PAIRS_BUCKET = 256
+MIN_WORDS_BUCKET = 1024
+
 
 def have_jax() -> bool:
+    """Whether jax imports.  Only a missing jax counts as absent: any other
+    failure propagates, and the device route itself raises if the backend
+    cannot start."""
     global _HAVE_JAX
     if _HAVE_JAX is None:
         try:
             import jax  # noqa: F401
-            _HAVE_JAX = True
-        except Exception:
+        except ImportError:
             _HAVE_JAX = False
+        else:
+            _HAVE_JAX = True
     return _HAVE_JAX
 
 
 def resolve_impl(impl: str) -> str:
-    """"auto" -> the kernel/ref route when jax imports, else numpy."""
+    """"auto" -> the device route when jax imports, else numpy."""
     if impl == "auto":
         return "jax" if have_jax() else "numpy"
     if impl not in ("numpy", "jax"):
@@ -159,15 +171,42 @@ def resolve_impl(impl: str) -> str:
     return impl
 
 
+def bucket(n: int, floor: int) -> int:
+    """Smallest power of two >= ``n`` and >= ``floor``."""
+    return max(floor, 1 << max(n - 1, 0).bit_length())
+
+
+def probe_pairs_device(lo, hi, word_off, num_words, bits_concat, k_hashes):
+    """The ragged pairs probe as one jitted call on the default JAX device.
+
+    Pairs and filter words are zero-padded to :func:`bucket` sizes; padded
+    pairs probe ``off=0, num_words=1``.  Returns the padded int32 hit mask
+    as a device array: its first ``len(lo)`` entries answer the pairs.
+    """
+    from ..kernels.bloom_probe.ops import probe_pairs as probe_pairs_jit
+    n = len(lo)
+    pp = bucket(n, MIN_PAIRS_BUCKET)
+    pw = bucket(len(bits_concat), MIN_WORDS_BUCKET)
+
+    def pad(a, size, dtype, fill=0):
+        out = np.full(size, fill, dtype=dtype)
+        out[:len(a)] = a
+        return out
+
+    return probe_pairs_jit(pad(lo, pp, np.uint32), pad(hi, pp, np.uint32),
+                           pad(word_off, pp, np.int32),
+                           pad(num_words, pp, np.uint32, fill=1),
+                           pad(bits_concat, pw, np.uint32),
+                           k_hashes=int(k_hashes))
+
+
 def probe_pairs(lo, hi, word_off, num_words, bits_concat, k_hashes,
                 impl: str = "numpy") -> np.ndarray:
     """Dispatch the ragged pairs probe to the selected implementation."""
     if resolve_impl(impl) == "jax":
-        from ..kernels.bloom_probe.ref import bloom_probe_pairs_ref
-        out = bloom_probe_pairs_ref(lo, hi, word_off.astype(np.int32),
-                                    num_words.astype(np.uint32),
-                                    bits_concat, k_hashes=k_hashes)
-        return np.asarray(out).astype(bool)
+        out = probe_pairs_device(lo, hi, word_off, num_words, bits_concat,
+                                 k_hashes)
+        return np.asarray(out)[:len(lo)].astype(bool)
     return probe_pairs_np(lo, hi, word_off, num_words, bits_concat, k_hashes)
 
 

@@ -546,14 +546,19 @@ class LSMTree:
                     if dt > 0:
                         yield dt * (1.0 / max(pace, 0.05) - 1.0)
                 outputs.append(sst)
-            # install outputs, delete inputs
+            # swap inputs for outputs in the levels, then free the inputs'
+            # zones: freeing wakes WAL-stalled writers at once, and a
+            # writer that rotates a memtable may pick the next compaction
+            # — it must see the outputs installed, or an L0 pick misses
+            # their overlap and installs L1 files on top of them
             for s in inputs:
                 self._remove_sst(s)
                 self.block_cache.drop_sst(s.sid)
-                self.backend.delete_sst(s)
             for s in outputs:
                 self._install_sst(s, target)
             self.levels[target].sort(key=lambda s: s.min_key)
+            for s in inputs:
+                self.backend.delete_sst(s)
             self.backend.on_hint(CompactionDoneHint(
                 cid=cid, target_level=target, num_selected=len(inputs),
                 num_generated=len(outputs),

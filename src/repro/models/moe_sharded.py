@@ -29,7 +29,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..config import ModelConfig
 
@@ -136,9 +135,9 @@ def moe_shard_map(p, cfg: ModelConfig, x: jnp.ndarray, mesh: Mesh,
         return y.astype(xl.dtype)
 
     body = ep_body if expert_parallel else tp_body
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(x_spec, wspecs["router"], wspecs["we_gate"],
-                             wspecs["we_up"], wspecs["we_down"]),
-                   out_specs=x_spec,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(x_spec, wspecs["router"], wspecs["we_gate"],
+                                 wspecs["we_up"], wspecs["we_down"]),
+                       out_specs=x_spec,
+                       check_vma=False)
     return fn(x, p["router"], p["we_gate"], p["we_up"], p["we_down"])

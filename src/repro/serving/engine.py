@@ -2,9 +2,9 @@
 
 A compact but real engine: request queue -> admission -> prefill ->
 interleaved decode with continuous batching.  The KV cache is paged and
-two-tier (HBM/host) under the HHZS-style manager; decode attention runs
-through the paged-attention kernel (interpret mode off-TPU) or its jnp
-reference.  Preemption on HBM pressure *is* capacity migration; resumption
+two-tier (HBM/host) under the HHZS-style manager; attention runs in jnp
+(``models.layers.sdpa``) over the KV gathered from the sequence's pages.
+Preemption on HBM pressure *is* capacity migration; resumption
 *is* popularity migration; prefix caching covers resumed sequences' first
 pages — the paper's three techniques, end to end, on the serving path.
 
@@ -29,6 +29,37 @@ except ImportError:                     # pragma: no cover - no-jax CI leg
 from ..config import ModelConfig
 from .paged_kv import PagedPool
 from .tiering import HHZSKVManager
+
+
+def _layer_forward(cfg: ModelConfig, layers, li, x, positions, pk, pv):
+    """Layer ``li`` over new tokens ``x`` [1, T, d] attending to the
+    resident KV ``pk``/``pv`` [L, S_prev, KV, D] plus their own.  Returns
+    (x, k, v) with the new tokens' k/v [T, KV, D].  The layer index is
+    traced, so every layer shares one program per (T, S_prev)."""
+    layer = jax.tree.map(lambda a: a[li], layers)
+    h = L.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    q, k, v = L._project_qkv(layer["attn"], cfg, h, h)
+    q = L.apply_rope(q, positions, cfg.rope_theta)
+    k = L.apply_rope(k, positions, cfg.rope_theta)
+    full_k = jnp.concatenate([pk[li], k[0]], axis=0)[None]
+    full_v = jnp.concatenate([pv[li], v[0]], axis=0)[None]
+    out = L.sdpa(q, full_k, full_v, cfg.num_heads // cfg.num_kv_heads,
+                 causal=True, q_offset=pk.shape[1])
+    x = x + out @ layer["attn"]["wo"]
+    h = L.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+    return x + L.mlp(layer["mlp"], cfg, h), k[0], v[0]
+
+
+def _take_pages(a, pages, n: int):
+    """The first ``n`` tokens held in ``pages`` of a pool array
+    [L, P, page, KV, D], all layers: [L, n, KV, D]."""
+    a = a[:, pages]
+    return a.reshape(a.shape[0], -1, *a.shape[3:])[:, :n]
+
+
+if jax is not None:
+    _layer_forward = jax.jit(_layer_forward, static_argnums=(0,))
+    _take_pages_device = jax.jit(_take_pages, static_argnums=(2,))
 
 
 @dataclass
@@ -86,54 +117,44 @@ class ServingEngine:
         seq = self.mgr.seqs[req.rid]
         x = p["embed"][jnp.asarray(tokens)[None, :]]     # [1, T, d]
         positions = (jnp.arange(len(tokens)) + seq.length)[None, :]
-        kv_cached = []                                    # per layer (k, v)
+        pk, pv = self._gather_kv(req)                     # [L, S_prev, KV, D]
+        ks, vs = [], []
         for li in range(cfg.num_layers):
-            layer = jax.tree.map(lambda a: a[li], p["layers"])
-            h = L.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-            q, k, v = L._project_qkv(layer["attn"], cfg, h, h)
-            q = L.apply_rope(q, positions, cfg.rope_theta)
-            k = L.apply_rope(k, positions, cfg.rope_theta)
-            kv_cached.append((k[0], v[0]))
-            # attention over (resident KV) + (new tokens)
-            pk, pv = self._gather_kv(req, li)             # [S_prev, KV, D]
-            full_k = jnp.concatenate([pk, k[0]], axis=0)[None]
-            full_v = jnp.concatenate([pv, v[0]], axis=0)[None]
-            out = L.sdpa(q, full_k, full_v,
-                         cfg.num_heads // cfg.num_kv_heads, causal=True,
-                         q_offset=int(seq.length))
-            x = x + out @ layer["attn"]["wo"]
-            h = L.rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-            x = x + L.mlp(layer["mlp"], cfg, h)
+            x, k, v = _layer_forward(cfg, p["layers"], jnp.int32(li), x,
+                                     positions, pk, pv)
+            ks.append(k)
+            vs.append(v)
         # append KV token by token (zone write pointers advance append-only)
+        lk = np.asarray(jnp.stack(ks))                    # [L, T, KV, D]
+        lv = np.asarray(jnp.stack(vs))
         for t in range(len(tokens)):
             zone = self.mgr.writable_zone(seq)
             pool = self.mgr.pool_of(seq)
-            lk = jnp.stack([kv[0][t] for kv in kv_cached])   # [L, KV, D]
-            lv = jnp.stack([kv[1][t] for kv in kv_cached])
-            pool.write_token(zone, lk, lv)
+            pool.write_token(zone, lk[:, t], lv[:, t])
             seq.length += 1
         x = L.rms_norm(x, p["final_norm"], cfg.norm_eps)
         logits = x[0, -1] @ M.lm_head(cfg, p)
         return int(jnp.argmax(logits))
 
-    def _gather_kv(self, req: Request, layer: int):
-        """All resident KV of a sequence for one layer: [S, KV, D]."""
+    def _gather_kv(self, req: Request):
+        """All resident KV of a sequence: ([L, S, KV, D], [L, S, KV, D]).
+        Host-tier pages are gathered on the host and copied over once;
+        HBM-tier pages are gathered on the device."""
         seq = self.mgr.seqs[req.rid]
         pool = self.mgr.pool_of(seq)
-        ks, vs = [], []
-        remaining = seq.length
-        for z in seq.zones:
-            for pg in z.pages:
-                take = min(remaining, self.page_size)
-                if take <= 0:
-                    break
-                ks.append(jnp.asarray(pool.k[layer, pg, :take]))
-                vs.append(jnp.asarray(pool.v[layer, pg, :take]))
-                remaining -= take
-        if not ks:
-            d = (0, self.cfg.num_kv_heads, self.cfg.head_dim_)
+        n = seq.length
+        pages = [pg for z in seq.zones for pg in z.pages]
+        pages = pages[:-(-n // self.page_size)]
+        if not pages:
+            d = (pool.k.shape[0], 0, self.cfg.num_kv_heads,
+                 self.cfg.head_dim_)
             return jnp.zeros(d, jnp.float32), jnp.zeros(d, jnp.float32)
-        return jnp.concatenate(ks), jnp.concatenate(vs)
+        if isinstance(pool.k, np.ndarray):
+            return (jnp.asarray(_take_pages(pool.k, pages, n)),
+                    jnp.asarray(_take_pages(pool.v, pages, n)))
+        idx = jnp.asarray(pages, jnp.int32)
+        return (_take_pages_device(pool.k, idx, n),
+                _take_pages_device(pool.v, idx, n))
 
     # ------------------------------------------------------------------
     def step(self) -> None:

@@ -95,6 +95,25 @@ def _run_cell(matrix: ScenarioMatrix, idx: int):
     return idx, rows
 
 
+def _run_pool_cell(matrix: ScenarioMatrix, idx: int):
+    """Pool-worker entry: ``_run_cell``, refusing a store that would probe
+    Bloom filters on the JAX device before it serves an op.  A device
+    serves one process, and the parent or another worker may hold it."""
+    from ..lsm.filters import resolve_impl
+    build = matrix._fresh_db
+
+    def one_process_db(*args, **kwargs):
+        db = build(*args, **kwargs)
+        if resolve_impl(db.scenario.lsm.filter_impl) == "jax":
+            raise ValueError(
+                "run_sweep: this cell's store probes Bloom filters on the "
+                "JAX device, and a device serves one process; run it with "
+                "workers=0")
+        return db
+    matrix._fresh_db = one_process_db       # this worker's copy only
+    return _run_cell(matrix, idx)
+
+
 def parse_cell_selector(spec: Optional[str]) -> Callable[[int, str], bool]:
     """Build a (index, cell-name) predicate from a ``--cells`` argument.
 
@@ -143,6 +162,10 @@ def run_sweep(matrix: ScenarioMatrix,
     row-identical to any ``workers>=1`` run by construction, since cells
     share no state.  ``validate`` (if given) is called on the merged row
     list before every write and must raise on schema violations.
+
+    Cells whose stores probe on the JAX device (``LSMConfig.filter_impl``
+    resolves to ``"jax"``) run only with ``workers=0``: a pool worker
+    raises on such a store, since an accelerator serves one process.
     """
     all_cells = matrix.cells()
     names = [c.name for c in all_cells]
@@ -238,7 +261,7 @@ def run_sweep(matrix: ScenarioMatrix,
                 idx = next(it, None)
                 if idx is None:
                     return False
-                in_flight[pool.submit(_run_cell, matrix, idx)] = idx
+                in_flight[pool.submit(_run_pool_cell, matrix, idx)] = idx
                 return True
 
             for _ in range(2 * workers):

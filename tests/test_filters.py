@@ -173,3 +173,74 @@ def test_tree_jax_impl_matches_numpy_impl():
         for key, got in zip(keys, answers[-1]):
             assert got == (key in model, model.get(key))
     assert answers[0] == answers[1]
+
+
+# ----------------------------------------------------------------------
+def _ragged_case(n_pairs, total_words, seed=0):
+    """Two packed filters filling ``total_words`` and ``n_pairs`` probes
+    spread over both (members and non-members)."""
+    rng = np.random.default_rng(seed)
+    w1 = max(1, total_words // 3)
+    sizes = [w1, total_words - w1] if total_words > 1 else [1]
+    bits, offs, members = [], [], []
+    for i, nw in enumerate(sizes):
+        keys = rng.integers(0, 2**63, nw * 3).astype(np.uint64)
+        lo, hi = filters.split_hash(keys)
+        bits.append(filters.build_filter_np(lo, hi, nw, 7))
+        offs.append(sum(sizes[:i]))
+        members.append(keys)
+    which = rng.integers(0, len(sizes), n_pairs)
+    keys = np.array([members[w][rng.integers(len(members[w]))]
+                     if rng.random() < 0.5 else rng.integers(2**63, 2**64,
+                                                             dtype=np.uint64)
+                     for w in which], dtype=np.uint64)
+    lo, hi = filters.split_hash(keys) if n_pairs else \
+        (np.zeros(0, np.uint32), np.zeros(0, np.uint32))
+    off = np.array([offs[w] for w in which], np.int64)
+    nw = np.array([sizes[w] for w in which], np.int64)
+    return lo, hi, off, nw, np.concatenate(bits)
+
+
+@pytest.mark.parametrize("n_pairs,total_words", [
+    # pair count at the bucket edges (floor 256), filter image fixed
+    (0, 1500), (1, 1500), (255, 1500), (256, 1500), (257, 1500),
+    (511, 1500), (512, 1500), (513, 1500),
+    # filter image at the bucket edges (floor 1024), pair count fixed
+    (300, 1), (300, 1023), (300, 1024), (300, 1025), (300, 2048),
+    (300, 2049),
+])
+def test_device_probe_padding_bit_identical(n_pairs, total_words):
+    """The jitted probe pads pairs and words to power-of-two buckets;
+    padding never changes an answer and is sliced off."""
+    pytest.importorskip("jax")
+    lo, hi, off, nw, bits = _ragged_case(n_pairs, total_words)
+    assert len(bits) == total_words
+    out = filters.probe_pairs_device(lo, hi, off, nw, bits, 7)
+    assert out.shape == (filters.bucket(n_pairs, filters.MIN_PAIRS_BUCKET),)
+    got = filters.probe_pairs(lo, hi, off, nw, bits, 7, impl="jax")
+    want = filters.probe_pairs_np(lo, hi, off, nw, bits, 7)
+    assert got.shape == (n_pairs,) and got.dtype == bool
+    assert (got == want).all()
+
+
+@pytest.mark.parametrize("n,floor,want", [
+    (0, 256, 256), (1, 256, 256), (256, 256, 256), (257, 256, 512),
+    (1023, 1024, 1024), (1025, 1024, 2048), (655360, 1024, 2 ** 20),
+])
+def test_bucket_is_next_power_of_two_above_floor(n, floor, want):
+    assert filters.bucket(n, floor) == want
+
+
+def test_have_jax_lets_only_import_errors_mean_absent(monkeypatch):
+    """A missing jax selects numpy; any other failure propagates."""
+    import builtins
+    real_import = builtins.__import__
+
+    def broken(name, *args, **kw):
+        if name == "jax":
+            raise RuntimeError("backend exploded")
+        return real_import(name, *args, **kw)
+    monkeypatch.setattr(filters, "_HAVE_JAX", None)
+    monkeypatch.setattr(builtins, "__import__", broken)
+    with pytest.raises(RuntimeError, match="backend exploded"):
+        filters.resolve_impl("auto")

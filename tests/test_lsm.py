@@ -128,6 +128,28 @@ def test_concurrent_burst_keeps_levels_disjoint():
         assert db.get(k) == (True, model[k])
 
 
+def test_freed_zones_wake_writers_only_after_outputs_install():
+    """Regression: a compaction freed its inputs' zones before installing
+    its outputs.  Freeing wakes WAL-stalled writers at once; one of them
+    rotated a memtable and picked an L0 compaction that could not see the
+    missing L1 outputs, then installed L1 files over them — loaded keys
+    read back as absent while reads ran beside the load's backlog."""
+    from repro.workloads.ycsb import READ, YCSB, OpStream, run_load
+    db = DB("HHZS", tiny_scenario())
+    run_load(db, n_keys=3000, seed=0)
+    db.flush_all()
+    stream = OpStream(db, YCSB["C"], 1024, 3000, seed=2)
+    keys = [stream.resolve(READ, int(r)) for r in stream.ops.args]
+    for i in range(0, len(keys), 256):
+        res = db.get_batch(keys[i:i + 256])
+        assert [k for k, (f, _) in zip(keys[i:i + 256], res) if not f] == []
+        for lvl in range(1, len(db.tree.levels)):
+            ssts = sorted(db.tree.levels[lvl], key=lambda s: s.min_key)
+            for a, b in zip(ssts, ssts[1:]):
+                assert a.max_key < b.min_key, \
+                    f"L{lvl} ranges overlap: {a.sid} and {b.sid}"
+
+
 def test_overwrite_returns_latest():
     db = DB("HHZS", tiny_scenario(), store_values=True)
     for ver in range(5):

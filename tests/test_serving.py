@@ -47,7 +47,7 @@ except ImportError:
     HAVE_JAX = False
 
 try:
-    from hypothesis import HealthCheck, given, settings
+    from hypothesis import HealthCheck, example, given, settings
     from hypothesis import strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:
@@ -601,15 +601,21 @@ def _check_zone_invariants(mgr, hbm, host):
         {z.zid for z in mgr.cache_pool}
 
 
+SCHEDULE_NEW_TOKENS = 4      # max_new_tokens of every scheduled sequence
+
+
 def _apply_schedule(policy, ops):
     hbm, host = _pools(hbm=4, host=24, materialize=False)
     mgr = make_manager(policy, hbm, host, cache_zones=1)
-    live, next_sid = [], 0
+    # as the engine does, admit prompt + max_new_tokens and stop decoding a
+    # sequence once it reaches that committed total
+    committed, live, next_sid = {}, [], 0
     for op, arg in ops:
         if op == "submit":
             tokens = 1 + arg % 20
-            if not mgr.admit(next_sid, tokens):
+            if not mgr.admit(next_sid, tokens + SCHEDULE_NEW_TOKENS):
                 continue
+            committed[next_sid] = tokens + SCHEDULE_NEW_TOKENS
             seq = mgr.on_prefill(next_sid, tokens)
             _fill(mgr, seq, tokens, materialized=False)
             live.append(next_sid)
@@ -618,7 +624,9 @@ def _apply_schedule(policy, ops):
             active = live[:1 + arg % 4]
             mgr.tick(active)
             for sid in active:
-                _fill(mgr, mgr.seqs[sid], 1, materialized=False)
+                seq = mgr.seqs[sid]
+                if seq.length < committed[sid]:
+                    _fill(mgr, seq, 1, materialized=False)
         elif op == "rotate" and live:   # churn: demote the head manually
             live.append(live.pop(0))
         elif op == "release" and live:
@@ -640,6 +648,10 @@ if HAVE_HYPOTHESIS:
                                           "release"]),
                          st.integers(min_value=0, max_value=40)),
                min_size=5, max_size=80))
+    # static: a step grew a full-zone sequence past its admitted budget
+    @example(policy="static", ops=[("submit", 0), ("submit", 7),
+                                   ("submit", 0), ("submit", 0),
+                                   ("step", 1)])
     def test_zone_accounting_property(policy, ops):
         _apply_schedule(policy, ops)
 
@@ -676,14 +688,14 @@ def test_gather_kv_matches_dense_reference():
         mgr=mgr, page_size=hbm.page_size,
         cfg=SimpleNamespace(num_kv_heads=KV, head_dim_=D))
     req = SimpleNamespace(rid=0)
+    k, v = ServingEngine._gather_kv(eng, req)
     for layer in range(L):
-        k, v = ServingEngine._gather_kv(eng, req, layer)
         want = np.stack([p[layer] for p in ref])
-        np.testing.assert_array_equal(np.asarray(k), want)
-        np.testing.assert_array_equal(np.asarray(v), want)
+        np.testing.assert_array_equal(np.asarray(k[layer]), want)
+        np.testing.assert_array_equal(np.asarray(v[layer]), want)
     mgr._seq_to_host(seq)               # migrate, then re-check
-    k, _ = ServingEngine._gather_kv(eng, req, 0)
-    np.testing.assert_array_equal(np.asarray(k),
+    k, _ = ServingEngine._gather_kv(eng, req)
+    np.testing.assert_array_equal(np.asarray(k[0]),
                                   np.stack([p[0] for p in ref]))
 
 

@@ -137,3 +137,35 @@ def test_validate_hook_gates_writes(tmp_path):
         run_sweep(tiny_matrix(schemes=("B3",), workloads=("A",)),
                   out=out, workers=0, verbose=False, validate=reject)
     assert not out.exists()
+
+
+def device_probe_db(scheme, ssd_zones):
+    """A caller's own store factory, outside the sweep module, whose
+    stores probe Bloom filters on the JAX device."""
+    from dataclasses import replace
+    from repro.lsm import DB, ScenarioConfig
+    from repro.workloads.ycsb import run_load
+    sc = ScenarioConfig(ssd_zones=ssd_zones)
+    db = DB(scheme, replace(sc, lsm=replace(sc.lsm, filter_impl="jax")))
+    db.n_keys = sc.paper_keys // 2048
+    run_load(db, n_keys=db.n_keys)
+    db.flush_all()
+    return db
+
+
+def test_device_probe_cells_refuse_worker_pools(tmp_path):
+    """A store that probes on the JAX device holds the device: a pool
+    worker refuses it, whatever factory built it, before it serves an
+    op; the same cell runs in the sweep's own process."""
+    pytest.importorskip("jax")
+    matrix = tiny_matrix(schemes=("HHZS",), workloads=("C",))
+    matrix.db_factory = device_probe_db
+    out = tmp_path / "dev.json"
+    with pytest.raises(ValueError, match="workers=0"):
+        run_sweep(matrix, out=out, workers=2, verbose=False)
+    assert not out.exists()
+    rows = run_sweep(matrix, workers=0, verbose=False)
+    assert len(rows) == 1 and rows[0]["op_counts"]["read"] > 0
+    # the numpy-probe factory still shards over workers
+    assert run_sweep(tiny_matrix(schemes=("HHZS",), workloads=("C",)),
+                     workers=1, verbose=False)
