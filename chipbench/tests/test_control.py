@@ -1,0 +1,23 @@
+"""The controls at a size a test run holds: the serving reference with
+its weights rounded below the configuration's bf16 (int8, float8), and a
+store whose device probe rejects keys its filters hold.  Each has to read
+clearly worse than the program, and the store's fails its cell."""
+from chipbench import harness, prove
+from conftest import smoke_serve_cell, tiny_store_cell
+
+
+def test_serving_controls_read_worse_than_the_program():
+    rows = {r["side"]: r for r in prove.serve_readings(smoke_serve_cell(),
+                                                       seed=9, seconds=2.0)}
+    prog = rows["program"]
+    assert prog["requests"] > 0 and prog["tokens"] > 0
+    for q in prove.QUANTS:
+        assert rows[f"control_{q}"]["kv_rel_err"] >= 3 * prog["kv_rel_err"]
+
+
+def test_store_control_is_not_correct():
+    row = prove.store_control(tiny_store_cell(), seed=9, seconds=1.0)
+    assert row["probe_mismatched_pairs"] > 0 and row["wrong_answers"] > 0
+    checks = [{"name": k, "value": row[k], "limit": 0}
+              for k in ("probe_mismatched_pairs", "wrong_answers")]
+    assert not harness.all_within(checks)
