@@ -17,6 +17,7 @@ from dataclasses import dataclass
 from typing import (Callable, Dict, FrozenSet, Generator, List, Optional,
                     Set, Tuple, TYPE_CHECKING, Union)
 
+from ..obs.spans import span
 from ..zoned.device import MiB, Zone, ZonedDevice, ZoneState
 from ..zoned.sim import Sim
 from .hinted_cache import HintedCache
@@ -138,7 +139,8 @@ class HybridZonedBackend:
     # hint entry point (LSM-tree -> middleware)
     # ==================================================================
     def on_hint(self, hint) -> None:
-        self.placement.on_hint(hint)
+        with span("hint"):
+            self.placement.on_hint(hint)
 
     # ==================================================================
     # SST I/O

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Tuple, TYPE_CHECKING
 
+from ..obs.spans import span
 from ..zoned.device import MiB
 
 if TYPE_CHECKING:
@@ -63,7 +64,8 @@ class Migrator:
     def _run(self):
         be = self.backend
         while True:
-            job = self._pick_job()
+            with span("migration.pick"):
+                job = self._pick_job()
             if job is None:
                 yield be.sim.timeout(self.tick, daemon=True)
                 continue

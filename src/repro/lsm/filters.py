@@ -27,6 +27,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs.spans import span
 from .sstable import SST, _mix64
 
 _LN2 = math.log(2.0)
@@ -145,6 +146,10 @@ _HAVE_JAX: Optional[bool] = None
 # batches it probes
 MIN_PAIRS_BUCKET = 256
 MIN_WORDS_BUCKET = 1024
+# bytes one padded pair hands the device: lo, hi, word offset and word
+# count, 4 bytes each; a filter word is 4 bytes
+PAIR_BYTES = 16
+WORD_BYTES = 4
 
 
 def have_jax() -> bool:
@@ -176,28 +181,40 @@ def bucket(n: int, floor: int) -> int:
     return max(floor, 1 << max(n - 1, 0).bit_length())
 
 
+def padded_sizes(pairs: int, words: int) -> Tuple[int, int]:
+    """(pairs, words) as the device probe pads them."""
+    return bucket(pairs, MIN_PAIRS_BUCKET), bucket(words, MIN_WORDS_BUCKET)
+
+
+def padded_bytes(pairs: int, words: int) -> int:
+    """Bytes one device probe call hands the device for ``pairs`` pairs
+    against a filter image of ``words`` words, padding included."""
+    pp, pw = padded_sizes(pairs, words)
+    return PAIR_BYTES * pp + WORD_BYTES * pw
+
+
 def probe_pairs_device(lo, hi, word_off, num_words, bits_concat, k_hashes):
     """The ragged pairs probe as one jitted call on the default JAX device.
 
-    Pairs and filter words are zero-padded to :func:`bucket` sizes; padded
+    Pairs and filter words are zero-padded to :func:`padded_sizes`; padded
     pairs probe ``off=0, num_words=1``.  Returns the padded int32 hit mask
     as a device array: its first ``len(lo)`` entries answer the pairs.
     """
     from ..kernels.bloom_probe.ops import probe_pairs as probe_pairs_jit
-    n = len(lo)
-    pp = bucket(n, MIN_PAIRS_BUCKET)
-    pw = bucket(len(bits_concat), MIN_WORDS_BUCKET)
+    pp, pw = padded_sizes(len(lo), len(bits_concat))
 
     def pad(a, size, dtype, fill=0):
         out = np.full(size, fill, dtype=dtype)
         out[:len(a)] = a
         return out
 
-    return probe_pairs_jit(pad(lo, pp, np.uint32), pad(hi, pp, np.uint32),
-                           pad(word_off, pp, np.int32),
-                           pad(num_words, pp, np.uint32, fill=1),
-                           pad(bits_concat, pw, np.uint32),
-                           k_hashes=int(k_hashes))
+    with span("probe.pad", words=pw):
+        args = (pad(lo, pp, np.uint32), pad(hi, pp, np.uint32),
+                pad(word_off, pp, np.int32),
+                pad(num_words, pp, np.uint32, fill=1),
+                pad(bits_concat, pw, np.uint32))
+    with span("probe.call"):
+        return probe_pairs_jit(*args, k_hashes=int(k_hashes))
 
 
 def probe_pairs(lo, hi, word_off, num_words, bits_concat, k_hashes,
@@ -206,7 +223,8 @@ def probe_pairs(lo, hi, word_off, num_words, bits_concat, k_hashes,
     if resolve_impl(impl) == "jax":
         out = probe_pairs_device(lo, hi, word_off, num_words, bits_concat,
                                  k_hashes)
-        return np.asarray(out)[:len(lo)].astype(bool)
+        with span("probe.read"):
+            return np.asarray(out)[:len(lo)].astype(bool)
     return probe_pairs_np(lo, hi, word_off, num_words, bits_concat, k_hashes)
 
 
@@ -215,10 +233,11 @@ def probe_pairs(lo, hi, word_off, num_words, bits_concat, k_hashes,
 # ----------------------------------------------------------------------
 def attach_filter(sst: SST, bits_per_key: int) -> None:
     """Build and attach the packed filter for an SST's key set."""
-    num_words, k = filter_params(sst.num_objs, bits_per_key)
-    lo, hi = split_hash(sst.keys)
-    sst.filter_words = build_filter_np(lo, hi, num_words, k)
-    sst.filter_k = k
+    with span("filter.build"):
+        num_words, k = filter_params(sst.num_objs, bits_per_key)
+        lo, hi = split_hash(sst.keys)
+        sst.filter_words = build_filter_np(lo, hi, num_words, k)
+        sst.filter_k = k
 
 
 def concat_filters(ssts: Sequence[SST]) -> Tuple[np.ndarray, dict]:

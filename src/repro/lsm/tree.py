@@ -21,6 +21,7 @@ import numpy as np
 from ..core.hints import (CompactionDoneHint, CompactionOutputHint,
                           CompactionTriggerHint, FlushHint)
 from ..core.middleware import HybridZonedBackend
+from ..obs.spans import span
 from ..zoned.sim import Semaphore, Sim
 from . import filters
 from .block_cache import BlockCache
@@ -126,6 +127,9 @@ class LSMTree:
             "puts": 0, "gets": 0, "hits": 0, "scans": 0,
             "write_stalls": 0, "compactions": 0, "flushes": 0,
             "bloom_fp": 0, "filter_probes": 0, "delayed_writes": 0,
+            # device probe calls, and the padded bytes each handed the
+            # device (``filters.padded_bytes``)
+            "probe_calls": 0, "probe_h2d_bytes": 0,
         }
         # per-level read index (sorted candidate arrays + concatenated
         # filter image), rebuilt lazily whenever the level's membership
@@ -353,19 +357,20 @@ class LSMTree:
                 self._flushing = batch
                 gens = {m.gen for m in batch}
                 runs, tombs, values = [], [], {}
-                for m in reversed(batch):   # newest first
-                    ks = np.fromiter(m.data.keys(), dtype=np.uint64,
-                                     count=len(m.data))
-                    order = np.argsort(ks, kind="stable")
-                    ks = ks[order]
-                    tb = np.fromiter((m.data[int(k)][0] for k in ks),
-                                     dtype=np.bool_, count=len(ks))
-                    runs.append(ks)
-                    tombs.append(tb)
-                    if self.cfg.store_values:
-                        for k, (t, v) in m.data.items():
-                            values.setdefault(k, v)
-                keys, tb = merge_runs(runs, tombs)
+                with span("flush.merge"):
+                    for m in reversed(batch):   # newest first
+                        ks = np.fromiter(m.data.keys(), dtype=np.uint64,
+                                         count=len(m.data))
+                        order = np.argsort(ks, kind="stable")
+                        ks = ks[order]
+                        tb = np.fromiter((m.data[int(k)][0] for k in ks),
+                                         dtype=np.bool_, count=len(ks))
+                        runs.append(ks)
+                        tombs.append(tb)
+                        if self.cfg.store_values:
+                            for k, (t, v) in m.data.items():
+                                values.setdefault(k, v)
+                    keys, tb = merge_runs(runs, tombs)
                 # flush->SST lineage: the batch's per-tenant write-volume
                 # shares become each output SST's tenant byte composition
                 tally: Dict[str, int] = {}
@@ -404,15 +409,16 @@ class LSMTree:
 
     def _make_sst(self, keys: np.ndarray, tombs: np.ndarray, level: int,
                   values: Optional[dict] = None) -> SST:
-        vals = None
-        if self.cfg.store_values and values is not None:
-            vals = {int(k): values.get(int(k)) for k in keys}
-        sst = SST(sid=self._new_sst_id(), level=level, keys=keys,
-                  tombs=tombs, obj_size=self.cfg.obj_size,
-                  block_size=self.cfg.block_size, birth=self.sim.now,
-                  values=vals)
-        if self.cfg.filters == "real":
-            filters.attach_filter(sst, self.cfg.filter_bits_per_key)
+        with span("sst.build"):
+            vals = None
+            if self.cfg.store_values and values is not None:
+                vals = {int(k): values.get(int(k)) for k in keys}
+            sst = SST(sid=self._new_sst_id(), level=level, keys=keys,
+                      tombs=tombs, obj_size=self.cfg.obj_size,
+                      block_size=self.cfg.block_size, birth=self.sim.now,
+                      values=vals)
+            if self.cfg.filters == "real":
+                filters.attach_filter(sst, self.cfg.filter_bits_per_key)
         return sst
 
     def _wake_stalled(self) -> None:
@@ -474,7 +480,6 @@ class LSMTree:
     def _compaction_job(self, level: int, inputs: List[SST]) -> Generator:
         yield self.jobs.acquire()
         cid = self._next_cid = self._next_cid + 1
-        cfg = self.cfg
         target = level + 1
         try:
             self.backend.on_hint(CompactionTriggerHint(
@@ -498,36 +503,8 @@ class LSMTree:
                         if dt > 0:
                             yield dt * (1.0 / max(pace, 0.05) - 1.0)
                     rem -= n
-            # merge: newest version wins; inputs ordered newest-priority first
-            src_lvl = [s for s in inputs if s.level == level]
-            dst_lvl = [s for s in inputs if s.level == target]
-            ordered = (sorted(src_lvl, key=lambda s: -s.birth) + dst_lvl
-                       if level == 0 else src_lvl + dst_lvl)
-            keys, tombs = merge_runs([s.keys for s in ordered],
-                                     [s.tombs for s in ordered])
-            values = None
-            if cfg.store_values:
-                values = {}
-                for s in ordered:
-                    if s.values:
-                        for k, v in s.values.items():
-                            values.setdefault(k, v)
-            # drop tombstones when compacting into the last populated level
-            bottom = all(not self.levels[l] for l in
-                         range(target + 1, len(self.levels)))
-            if bottom and len(keys):
-                keep = ~tombs
-                keys, tombs = keys[keep], tombs[keep]
-            # compaction lineage: outputs inherit the inputs' pooled
-            # tenant byte composition, scaled to each output's size
-            in_attr: Dict[str, float] = {}
-            in_bytes = 0
-            for s in inputs:
-                in_bytes += s.size_bytes
-                for t, b in getattr(s, "tenant_bytes", {}).items():
-                    in_attr[t] = in_attr.get(t, 0.0) + b
-            comp = ({t: b / in_bytes for t, b in in_attr.items()}
-                    if in_bytes > 0 else {})
+            with span("compaction.merge"):
+                keys, tombs, values, comp = self._merge_inputs(level, inputs)
             outputs: List[SST] = []
             for ks, tbs in self._split_sst(keys, tombs):
                 if not len(ks):
@@ -572,6 +549,42 @@ class LSMTree:
             self._wake_stalled()
         self._kick_background()
 
+    def _merge_inputs(self, level: int, inputs: List[SST]):
+        """One compaction's merged runs: (keys, tombs, values, tenant
+        composition), newest version winning."""
+        target = level + 1
+        # inputs ordered newest-priority first
+        src_lvl = [s for s in inputs if s.level == level]
+        dst_lvl = [s for s in inputs if s.level == target]
+        ordered = (sorted(src_lvl, key=lambda s: -s.birth) + dst_lvl
+                   if level == 0 else src_lvl + dst_lvl)
+        keys, tombs = merge_runs([s.keys for s in ordered],
+                                 [s.tombs for s in ordered])
+        values = None
+        if self.cfg.store_values:
+            values = {}
+            for s in ordered:
+                if s.values:
+                    for k, v in s.values.items():
+                        values.setdefault(k, v)
+        # drop tombstones when compacting into the last populated level
+        bottom = all(not self.levels[l] for l in
+                     range(target + 1, len(self.levels)))
+        if bottom and len(keys):
+            keep = ~tombs
+            keys, tombs = keys[keep], tombs[keep]
+        # compaction lineage: outputs inherit the inputs' pooled
+        # tenant byte composition, scaled to each output's size
+        in_attr: Dict[str, float] = {}
+        in_bytes = 0
+        for s in inputs:
+            in_bytes += s.size_bytes
+            for t, b in getattr(s, "tenant_bytes", {}).items():
+                in_attr[t] = in_attr.get(t, 0.0) + b
+        comp = ({t: b / in_bytes for t, b in in_attr.items()}
+                if in_bytes > 0 else {})
+        return keys, tombs, values, comp
+
     # ==================================================================
     # read path
     # ==================================================================
@@ -602,17 +615,18 @@ class LSMTree:
         cached = self._ridx.get(lvl)
         if cached is not None and cached[0] == self._level_epoch[lvl]:
             return cached[1]
-        if lvl == 0:
-            ssts = sorted(self.levels[0], key=lambda s: -s.birth)
-            mins: List[int] = []
-            mins_np = None
-        else:
-            ssts = sorted(self.levels[lvl], key=lambda s: s.min_key)
-            mins = [s.min_key for s in ssts]
-            mins_np = np.array(mins, dtype=np.uint64)
-        maxs = [s.max_key for s in ssts]
-        bits, offsets = (filters.concat_filters(ssts)
-                         if self.cfg.filters == "real" else (None, None))
+        with span("level_index"):
+            if lvl == 0:
+                ssts = sorted(self.levels[0], key=lambda s: -s.birth)
+                mins: List[int] = []
+                mins_np = None
+            else:
+                ssts = sorted(self.levels[lvl], key=lambda s: s.min_key)
+                mins = [s.min_key for s in ssts]
+                mins_np = np.array(mins, dtype=np.uint64)
+            maxs = [s.max_key for s in ssts]
+            bits, offsets = (filters.concat_filters(ssts)
+                             if self.cfg.filters == "real" else (None, None))
         idx = (ssts, mins, mins_np, maxs, bits, offsets)
         self._ridx[lvl] = (self._level_epoch[lvl], idx)
         return idx
@@ -642,13 +656,16 @@ class LSMTree:
         or device read), logical-read accounting, tombstone check.
         Returns (found, value|None) or None when the key is absent (a
         Bloom false positive)."""
-        found, idx = sst.find(key)
-        blk = sst.block_of(idx if found else
-                           min(idx, max(sst.num_objs - 1, 0)))
-        # logical read: the §3.4 popularity signal counts cache hits too —
-        # a fully cache-resident hot SST must not look cold to the migrator
-        sst.num_reads += 1
-        if not self.block_cache.get(sst.sid, blk):
+        with span("block_lookup"):
+            found, idx = sst.find(key)
+            blk = sst.block_of(idx if found else
+                               min(idx, max(sst.num_objs - 1, 0)))
+            # logical read: the §3.4 popularity signal counts cache hits
+            # too — a fully cache-resident hot SST must not look cold to
+            # the migrator
+            sst.num_reads += 1
+            cached = self.block_cache.get(sst.sid, blk)
+        if not cached:
             yield from self.backend.read_block(sst, blk)
             self.block_cache.insert(sst.sid, blk)
         if found:
@@ -702,37 +719,13 @@ class LSMTree:
                 break
             if not self.levels[lvl]:
                 continue
-            idx = self._level_index(lvl)
-            ssts, _, mins_np, maxs, bits, offsets = idx
-            # candidate pairs, grouped per key in lookup order; deeper
-            # levels are disjoint, so one searchsorted over the whole
-            # batch replaces per-key range scans
-            pair_of: List[List[SST]] = []
-            if lvl == 0:
-                for i in pending:
-                    k = keys[i]
-                    pair_of.append([s for s in ssts
-                                    if s.min_key <= k <= s.max_key])
-            else:
-                karr = np.fromiter((keys[i] for i in pending),
-                                   np.uint64, len(pending))
-                pos = np.searchsorted(mins_np, karr, side="right") - 1
-                for t, i in enumerate(pending):
-                    j = int(pos[t])
-                    pair_of.append([ssts[j]] if j >= 0
-                                   and keys[i] <= maxs[j] else [])
-            flat = [(i, sst) for i, cands in zip(pending, pair_of)
-                    for sst in cands]
+            # the level's candidates and their probe; the span closes
+            # before the survivors' walk, which yields
+            with span("get_batch.level", level=lvl):
+                pair_of, flat, hits = self._probe_level(lvl, keys, pending,
+                                                        real)
             if not flat:
                 continue
-            if real:
-                hits = self._probe_pairs_real(
-                    np.array([keys[i] for i, _ in flat], dtype=np.uint64),
-                    [sst for _, sst in flat], bits, offsets)
-            else:
-                hits = [sst.bloom_maybe_contains(keys[i],
-                                                 self.cfg.bloom_fp_rate)
-                        for i, sst in flat]
             # walk survivors per key in candidate order, stopping at the
             # first exact hit — byte-identical I/O to the per-key path
             self.stats["filter_probes"] += len(flat)
@@ -754,6 +747,41 @@ class LSMTree:
             results[i] = (False, None)
         return results
 
+    def _probe_level(self, lvl: int, keys: List[int], pending: List[int],
+                     real: bool):
+        """One level of :meth:`get_batch`: the candidate SSTs of each
+        pending key, grouped per key in lookup order, the flat (key index,
+        SST) pairs, and the Bloom answer for each pair."""
+        ssts, _, mins_np, maxs, bits, offsets = self._level_index(lvl)
+        # deeper levels are disjoint, so one searchsorted over the whole
+        # batch replaces per-key range scans
+        pair_of: List[List[SST]] = []
+        if lvl == 0:
+            for i in pending:
+                k = keys[i]
+                pair_of.append([s for s in ssts
+                                if s.min_key <= k <= s.max_key])
+        else:
+            karr = np.fromiter((keys[i] for i in pending),
+                               np.uint64, len(pending))
+            pos = np.searchsorted(mins_np, karr, side="right") - 1
+            for t, i in enumerate(pending):
+                j = int(pos[t])
+                pair_of.append([ssts[j]] if j >= 0
+                               and keys[i] <= maxs[j] else [])
+        flat = [(i, sst) for i, cands in zip(pending, pair_of)
+                for sst in cands]
+        if not flat:
+            return pair_of, flat, None
+        if real:
+            hits = self._probe_pairs_real(
+                np.array([keys[i] for i, _ in flat], dtype=np.uint64),
+                [sst for _, sst in flat], bits, offsets)
+        else:
+            hits = [sst.bloom_maybe_contains(keys[i], self.cfg.bloom_fp_rate)
+                    for i, sst in flat]
+        return pair_of, flat, hits
+
     def _probe_pairs_real(self, pair_keys: np.ndarray,
                           pair_ssts: List[SST],
                           bits: Optional[np.ndarray] = None,
@@ -767,13 +795,19 @@ class LSMTree:
         mask = np.array([s.sid in offsets for s in pair_ssts], dtype=bool)
         if not mask.any():
             return hits
-        lo, hi = filters.split_hash(pair_keys[mask])
-        sel = [s for s in pair_ssts if s.sid in offsets]
-        off = np.array([offsets[s.sid][0] for s in sel], dtype=np.int64)
-        nw = np.array([offsets[s.sid][1] for s in sel], dtype=np.int64)
-        k = max(s.filter_k for s in sel)
-        hits[mask] = filters.probe_pairs(lo, hi, off, nw, bits, k,
-                                         impl=self.cfg.filter_impl)
+        with span("probe"):
+            lo, hi = filters.split_hash(pair_keys[mask])
+            sel = [s for s in pair_ssts if s.sid in offsets]
+            off = np.array([offsets[s.sid][0] for s in sel], dtype=np.int64)
+            nw = np.array([offsets[s.sid][1] for s in sel], dtype=np.int64)
+            k = max(s.filter_k for s in sel)
+            impl = filters.resolve_impl(self.cfg.filter_impl)
+            hits[mask] = filters.probe_pairs(lo, hi, off, nw, bits, k,
+                                             impl=impl)
+        if impl == "jax":
+            self.stats["probe_calls"] += 1
+            self.stats["probe_h2d_bytes"] += filters.padded_bytes(len(lo),
+                                                                  len(bits))
         return hits
 
     def scan(self, start_key: int, count: int) -> Generator:

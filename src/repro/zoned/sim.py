@@ -249,6 +249,13 @@ class Sim:
         # dead processes after the crash
         self.graveyard: List = []
 
+    @property
+    def scheduled(self) -> int:
+        """Entries scheduled so far: timeouts, process starts, absolute
+        and bulk schedules, device completions, and the resumes of
+        processes that yield a bare delay (each takes one ``_seq``)."""
+        return self._seq
+
     # -- scheduling -------------------------------------------------------
     def timeout(self, delay: float, value: Any = None,
                 daemon: bool = False) -> Event:
