@@ -23,6 +23,8 @@ agreement is asserted by ``tests/test_filters.py``.
 from __future__ import annotations
 
 import math
+import weakref
+from collections import OrderedDict
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -150,6 +152,17 @@ MIN_WORDS_BUCKET = 1024
 # count, 4 bytes each; a filter word is 4 bytes
 PAIR_BYTES = 16
 WORD_BYTES = 4
+# padded device copies of read-only filter images, least recently used
+# first: id(host image) -> (weak reference to it, device array).  An entry
+# goes when its host image is freed, when its owner releases it
+# (:func:`release`), or when more than RESIDENT_IMAGES are held: a few
+# per level of a default tree
+_resident: "OrderedDict[int, tuple]" = OrderedDict()
+RESIDENT_IMAGES = 14
+# what the device route has handed the device since the process started:
+# filter images uploaded to stay resident, and bytes of every array handed
+# over (pairs, per-call images and uploads, padding included)
+transfers = {"uploads": 0, "h2d_bytes": 0}
 
 
 def have_jax() -> bool:
@@ -187,34 +200,88 @@ def padded_sizes(pairs: int, words: int) -> Tuple[int, int]:
 
 
 def padded_bytes(pairs: int, words: int) -> int:
-    """Bytes one device probe call hands the device for ``pairs`` pairs
-    against a filter image of ``words`` words, padding included."""
+    """Bytes a device probe call of ``pairs`` pairs hands the device when
+    it hands over its filter image of ``words`` words too, padding
+    included: every call with a writeable image, and the call that uploads
+    a read-only one.  A call against a resident image hands over only the
+    pairs, ``PAIR_BYTES`` each."""
     pp, pw = padded_sizes(pairs, words)
     return PAIR_BYTES * pp + WORD_BYTES * pw
+
+
+def _pad(a, size, dtype, fill=0):
+    out = np.full(size, fill, dtype=dtype)
+    out[:len(a)] = a
+    return out
+
+
+def _frozen(bits) -> bool:
+    """Whether ``bits`` is a filter image whose words cannot change: a
+    read-only array that owns its memory (``concat_filters``' images).  A
+    read-only view of a writeable array is not."""
+    return (isinstance(bits, np.ndarray) and not bits.flags.writeable
+            and bits.flags.owndata)
+
+
+def _drop(key: int, ref) -> None:
+    """Forget the entry of ``key`` if it is still the one ``ref`` names."""
+    hit = _resident.get(key)
+    if hit is not None and hit[0] is ref:
+        del _resident[key]
+
+
+def _resident_image(bits: np.ndarray, pw: int):
+    """The padded device copy of a frozen host image, uploaded on its first
+    probe.  The upload is uncommitted (``device_put`` with no device), so
+    the executables compiled for numpy arguments serve it unchanged."""
+    key = id(bits)
+    hit = _resident.get(key)
+    if hit is not None and hit[0]() is bits:
+        _resident.move_to_end(key)
+        return hit[1]
+    import jax
+    with span("probe.upload", words=pw):
+        image = jax.device_put(_pad(bits, pw, np.uint32))
+    ref = weakref.ref(bits, lambda r, key=key: _drop(key, r))
+    _resident[key] = (ref, image)
+    while len(_resident) > RESIDENT_IMAGES:
+        _resident.popitem(last=False)
+    transfers["uploads"] += 1
+    transfers["h2d_bytes"] += WORD_BYTES * pw
+    return image
+
+
+def release(bits) -> None:
+    """Free the device copy of a host image its owner will probe no more."""
+    hit = _resident.get(id(bits))
+    if hit is not None and hit[0]() is bits:
+        del _resident[id(bits)]
 
 
 def probe_pairs_device(lo, hi, word_off, num_words, bits_concat, k_hashes):
     """The ragged pairs probe as one jitted call on the default JAX device.
 
     Pairs and filter words are zero-padded to :func:`padded_sizes`; padded
-    pairs probe ``off=0, num_words=1``.  Returns the padded int32 hit mask
-    as a device array: its first ``len(lo)`` entries answer the pairs.
+    pairs probe ``off=0, num_words=1``.  A frozen image (read-only, owning
+    its memory) is padded and uploaded once and stays on the device while
+    its host array lives; any other image is padded and handed over on
+    every call.  Returns the padded int32 hit mask as a device array: its
+    first ``len(lo)`` entries answer the pairs.
     """
     from ..kernels.bloom_probe.ops import probe_pairs as probe_pairs_jit
     pp, pw = padded_sizes(len(lo), len(bits_concat))
-
-    def pad(a, size, dtype, fill=0):
-        out = np.full(size, fill, dtype=dtype)
-        out[:len(a)] = a
-        return out
-
+    resident = _frozen(bits_concat)
+    image = _resident_image(bits_concat, pw) if resident else None
     with span("probe.pad", words=pw):
-        args = (pad(lo, pp, np.uint32), pad(hi, pp, np.uint32),
-                pad(word_off, pp, np.int32),
-                pad(num_words, pp, np.uint32, fill=1),
-                pad(bits_concat, pw, np.uint32))
+        args = (_pad(lo, pp, np.uint32), _pad(hi, pp, np.uint32),
+                _pad(word_off, pp, np.int32),
+                _pad(num_words, pp, np.uint32, fill=1))
+        if not resident:
+            image = _pad(bits_concat, pw, np.uint32)
+    transfers["h2d_bytes"] += (PAIR_BYTES * pp if resident else
+                               padded_bytes(len(lo), len(bits_concat)))
     with span("probe.call"):
-        return probe_pairs_jit(*args, k_hashes=int(k_hashes))
+        return probe_pairs_jit(*args, image, k_hashes=int(k_hashes))
 
 
 def probe_pairs(lo, hi, word_off, num_words, bits_concat, k_hashes,
@@ -243,7 +310,8 @@ def attach_filter(sst: SST, bits_per_key: int) -> None:
 def concat_filters(ssts: Sequence[SST]) -> Tuple[np.ndarray, dict]:
     """Concatenate distinct SSTs' filter words for the pairs probe.
 
-    Returns (bits_concat, {sid: (word_off, num_words)}).
+    Returns (bits_concat, {sid: (word_off, num_words)}); ``bits_concat``
+    is read-only.
     """
     offsets: dict = {}
     chunks: List[np.ndarray] = []
@@ -257,4 +325,6 @@ def concat_filters(ssts: Sequence[SST]) -> Tuple[np.ndarray, dict]:
         off += len(w)
     bits = (np.concatenate(chunks) if chunks
             else np.zeros(0, dtype=np.uint32))
+    # frozen, so the device route may keep it resident (probe_pairs_device)
+    bits.flags.writeable = False
     return bits, offsets

@@ -127,14 +127,14 @@ class LSMTree:
             "puts": 0, "gets": 0, "hits": 0, "scans": 0,
             "write_stalls": 0, "compactions": 0, "flushes": 0,
             "bloom_fp": 0, "filter_probes": 0, "delayed_writes": 0,
-            # device probe calls, and the padded bytes each handed the
-            # device (``filters.padded_bytes``)
-            "probe_calls": 0, "probe_h2d_bytes": 0,
+            # device probe calls, the level images uploaded to stay on the
+            # device, and the bytes handed the device, padding included:
+            # each call's pairs, and each image when it is uploaded
+            "probe_calls": 0, "probe_image_uploads": 0, "probe_h2d_bytes": 0,
         }
         # per-level read index (sorted candidate arrays + concatenated
-        # filter image), rebuilt lazily whenever the level's membership
-        # epoch moves — see _level_index
-        self._level_epoch: List[int] = [0] * (cfg.num_levels + 2)
+        # filter image), dropped whenever the level's membership changes
+        # and rebuilt lazily — see _level_index
         self._ridx: Dict[int, Tuple] = {}
 
     # ------------------------------------------------------------------
@@ -156,13 +156,20 @@ class LSMTree:
         self.levels[level].append(sst)
         self._level_bytes[level] += sst.size_bytes
         self.manifest[sst.sid] = sst
-        self._level_epoch[level] += 1
+        self._membership_changed(level)
 
     def _remove_sst(self, sst: SST) -> None:
         self.levels[sst.level].remove(sst)
         self._level_bytes[sst.level] -= sst.size_bytes
         self.manifest.pop(sst.sid, None)
-        self._level_epoch[sst.level] += 1
+        self._membership_changed(sst.level)
+
+    def _membership_changed(self, level: int) -> None:
+        """Drop the level's read index, and free the device copy of its
+        filter image: the image is probed no more."""
+        idx = self._ridx.pop(level, None)
+        if idx is not None and idx[4] is not None:
+            filters.release(idx[4])
 
     def compaction_debt(self) -> int:
         return sum(max(0, self._level_bytes[l] - self.cfg.target_of(l))
@@ -600,8 +607,8 @@ class LSMTree:
         return None
 
     def _level_index(self, lvl: int):
-        """Read index for one level, rebuilt only when the level's
-        membership epoch moves (SST install/remove): candidate SSTs in
+        """Read index for one level, rebuilt only after the level's
+        membership changes (SST install/remove): candidate SSTs in
         lookup order, their key ranges as plain ints / a sorted uint64
         array for bisection, and the level's concatenated filter image
         for the vectorized batch probe.
@@ -613,8 +620,8 @@ class LSMTree:
         disjoint, so each key has at most one candidate, found by
         bisecting the sorted min-key array."""
         cached = self._ridx.get(lvl)
-        if cached is not None and cached[0] == self._level_epoch[lvl]:
-            return cached[1]
+        if cached is not None:
+            return cached
         with span("level_index"):
             if lvl == 0:
                 ssts = sorted(self.levels[0], key=lambda s: -s.birth)
@@ -628,7 +635,7 @@ class LSMTree:
             bits, offsets = (filters.concat_filters(ssts)
                              if self.cfg.filters == "real" else (None, None))
         idx = (ssts, mins, mins_np, maxs, bits, offsets)
-        self._ridx[lvl] = (self._level_epoch[lvl], idx)
+        self._ridx[lvl] = idx
         return idx
 
     def _level_candidates(self, lvl: int, key: int) -> List[SST]:
@@ -802,12 +809,15 @@ class LSMTree:
             nw = np.array([offsets[s.sid][1] for s in sel], dtype=np.int64)
             k = max(s.filter_k for s in sel)
             impl = filters.resolve_impl(self.cfg.filter_impl)
+            before = dict(filters.transfers)
             hits[mask] = filters.probe_pairs(lo, hi, off, nw, bits, k,
                                              impl=impl)
         if impl == "jax":
             self.stats["probe_calls"] += 1
-            self.stats["probe_h2d_bytes"] += filters.padded_bytes(len(lo),
-                                                                  len(bits))
+            self.stats["probe_image_uploads"] += (
+                filters.transfers["uploads"] - before["uploads"])
+            self.stats["probe_h2d_bytes"] += (
+                filters.transfers["h2d_bytes"] - before["h2d_bytes"])
         return hits
 
     def scan(self, start_key: int, count: int) -> Generator:

@@ -201,14 +201,17 @@ def _ragged_case(n_pairs, total_words, seed=0):
     return lo, hi, off, nw, np.concatenate(bits)
 
 
-@pytest.mark.parametrize("n_pairs,total_words", [
+_EDGES = [
     # pair count at the bucket edges (floor 256), filter image fixed
     (0, 1500), (1, 1500), (255, 1500), (256, 1500), (257, 1500),
     (511, 1500), (512, 1500), (513, 1500),
     # filter image at the bucket edges (floor 1024), pair count fixed
     (300, 1), (300, 1023), (300, 1024), (300, 1025), (300, 2048),
     (300, 2049),
-])
+]
+
+
+@pytest.mark.parametrize("n_pairs,total_words", _EDGES)
 def test_device_probe_padding_bit_identical(n_pairs, total_words):
     """The jitted probe pads pairs and words to power-of-two buckets;
     padding never changes an answer and is sliced off."""
@@ -221,6 +224,106 @@ def test_device_probe_padding_bit_identical(n_pairs, total_words):
     want = filters.probe_pairs_np(lo, hi, off, nw, bits, 7)
     assert got.shape == (n_pairs,) and got.dtype == bool
     assert (got == want).all()
+
+
+def _uploads(monkeypatch):
+    """Counts ``jax.device_put`` calls from here on; returns the log."""
+    import jax
+    log = []
+    real = jax.device_put
+
+    def put(x, *args, **kw):
+        log.append(np.asarray(x).shape)
+        return real(x, *args, **kw)
+    monkeypatch.setattr(jax, "device_put", put)
+    return log
+
+
+@pytest.mark.parametrize("n_pairs,total_words", _EDGES)
+def test_read_only_image_is_uploaded_once_and_answers_bit_identical(
+        n_pairs, total_words, monkeypatch):
+    """A read-only image is padded and uploaded on its first probe and
+    serves every later probe from the device, with the same answers."""
+    pytest.importorskip("jax")
+    lo, hi, off, nw, bits = _ragged_case(n_pairs, total_words)
+    bits.flags.writeable = False
+    log = _uploads(monkeypatch)
+    up0 = filters.transfers["uploads"]
+    want = filters.probe_pairs_np(lo, hi, off, nw, bits, 7)
+    for _ in range(3):
+        got = filters.probe_pairs(lo, hi, off, nw, bits, 7, impl="jax")
+        assert got.dtype == bool and (got == want).all()
+    pw = filters.bucket(total_words, filters.MIN_WORDS_BUCKET)
+    assert log == [(pw,)]
+    assert filters.transfers["uploads"] - up0 == 1
+
+
+def test_writeable_image_is_never_kept_on_the_device(monkeypatch):
+    pytest.importorskip("jax")
+    lo, hi, off, nw, bits = _ragged_case(40, 1500)
+    log = _uploads(monkeypatch)
+    before = dict(filters.transfers)
+    for _ in range(3):
+        got = filters.probe_pairs(lo, hi, off, nw, bits, 7, impl="jax")
+        assert (got == filters.probe_pairs_np(lo, hi, off, nw, bits,
+                                              7)).all()
+    assert log == [] and id(bits) not in filters._resident
+    assert filters.transfers["uploads"] == before["uploads"]
+    # each call hands over its padded pairs and its padded image
+    assert (filters.transfers["h2d_bytes"] - before["h2d_bytes"]
+            == 3 * filters.padded_bytes(40, 1500))
+    # a read-only view of a writeable array can still change: not kept
+    view = bits[:]
+    view.flags.writeable = False
+    filters.probe_pairs(lo, hi, off, nw, view, 7, impl="jax")
+    assert log == [] and id(view) not in filters._resident
+
+
+def test_resident_images_stay_within_bound_and_go_with_their_host_array(
+        monkeypatch):
+    pytest.importorskip("jax")
+    monkeypatch.setattr(filters, "RESIDENT_IMAGES", 3)
+    monkeypatch.setattr(filters, "_resident", type(filters._resident)())
+    lo, hi, off, nw, bits = _ragged_case(20, 1100)
+    log = _uploads(monkeypatch)
+    images = []
+    for _ in range(5):
+        img = bits.copy()
+        img.flags.writeable = False
+        images.append(img)
+        filters.probe_pairs(lo, hi, off, nw, img, 7, impl="jax")
+        assert len(filters._resident) <= 3
+    assert len(log) == 5
+    # the least recently used went first
+    assert [id(i) for i in images[2:]] == list(filters._resident)
+    filters.probe_pairs(lo, hi, off, nw, images[0], 7, impl="jax")
+    assert len(log) == 6 and id(images[2]) not in filters._resident
+    # a freed host image takes its device copy with it
+    gone = id(images[-1])
+    del images[-1], img
+    assert gone not in filters._resident and len(filters._resident) == 2
+    # and its owner may free it while the host array lives
+    filters.release(images[0])
+    assert id(images[0]) not in filters._resident
+    got = filters.probe_pairs(lo, hi, off, nw, images[0], 7, impl="jax")
+    assert len(log) == 7
+    assert (got == filters.probe_pairs_np(lo, hi, off, nw, bits, 7)).all()
+
+
+def test_concat_filters_returns_a_read_only_image():
+    rng = np.random.default_rng(9)
+    keys = rng.integers(0, 2**63, 300).astype(np.uint64)
+    lo, hi = filters.split_hash(keys)
+
+    class _Sst:
+        def __init__(self, sid, words):
+            self.sid, self.filter_words = sid, words
+    a = _Sst(1, filters.build_filter_np(lo, hi, 64, 7))
+    b = _Sst(2, filters.build_filter_np(hi, lo, 32, 7))
+    for ssts in ([a, b], [a], []):
+        bits, _ = filters.concat_filters(ssts)
+        assert not bits.flags.writeable and bits.flags.owndata
+    assert a.filter_words.flags.writeable
 
 
 @pytest.mark.parametrize("n,floor,want", [

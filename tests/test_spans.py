@@ -80,10 +80,10 @@ def _nest(log):
     return done
 
 
-def _jax_db(keys=1200):
+def _jax_db(keys=1200, impl="jax"):
     from repro.workloads import run_load
     sc = tiny_scenario()
-    sc = replace(sc, lsm=replace(sc.lsm, filter_impl="jax"))
+    sc = replace(sc, lsm=replace(sc.lsm, filter_impl=impl))
     db = DB("HHZS", sc, store_values=True)
     run_load(db, n_keys=keys)
     db.flush_all()
@@ -104,13 +104,14 @@ def test_spans_close_in_lifo_order_under_concurrent_reads_and_compaction(
     assert db.tree.stats["compactions"] > before["compactions"]
     names = {name for name, _, _ in done}
     assert {"get_batch.level", "level_index", "block_lookup", "probe",
-            "probe.pad", "probe.call", "probe.read", "hint",
+            "probe.upload", "probe.pad", "probe.call", "probe.read", "hint",
             "migration.pick", "compaction.merge", "flush.merge",
             "sst.build", "filter.build"} <= names
     for name, _, outer in done:
         if name == "filter.build":
             assert outer[-1] == "sst.build"
-        if name in ("probe.pad", "probe.call", "probe.read"):
+        if name in ("probe.upload", "probe.pad", "probe.call",
+                    "probe.read"):
             assert outer[-1] == "probe"
         if name == "probe":
             assert outer[-1] == "get_batch.level"
@@ -143,6 +144,7 @@ def test_one_level_span_per_probed_level_and_one_of_each_per_call(
         batch = [int(k) for k in rng.choice(n, size, replace=False)]
         visited, called = _expected_levels(db.tree, batch)
         calls0 = db.tree.stats["probe_calls"]
+        uploads0 = db.tree.stats["probe_image_uploads"]
         recorded.clear()
         got = db.get_batch(batch)
         assert all(found for found, _ in got)
@@ -153,29 +155,95 @@ def test_one_level_span_per_probed_level_and_one_of_each_per_call(
         assert db.tree.stats["probe_calls"] - calls0 == len(called)
         for name in ("probe", "probe.pad", "probe.call", "probe.read"):
             assert sum(1 for m, _, _ in done if m == name) == len(called)
+        uploads = [a["words"] for m, a, _ in done if m == "probe.upload"]
+        assert (db.tree.stats["probe_image_uploads"] - uploads0
+                == len(uploads) <= len(called))
         pads = [a["words"] for m, a, _ in done if m == "probe.pad"]
-        assert all(w >= 1024 and w & (w - 1) == 0 for w in pads)
+        assert all(w >= 1024 and w & (w - 1) == 0 for w in pads + uploads)
 
 
 def test_probe_h2d_bytes_are_the_padded_arrays_the_device_got(monkeypatch):
-    pytest.importorskip("jax")
+    """Each call hands over its host arrays (the padded pairs); a level's
+    image crosses once, when it is uploaded, and then stays."""
+    jax = pytest.importorskip("jax")
     from repro.kernels.bloom_probe import ops
     db, n = _jax_db()
-    handed = []
-    real = ops.probe_pairs
+    handed, uploaded = [], []
+    real, real_put = ops.probe_pairs, jax.device_put
 
     def probe_pairs(*arrays, k_hashes):
-        handed.append(sum(np.asarray(a).nbytes for a in arrays))
+        handed.append(sum(a.nbytes for a in arrays
+                          if isinstance(a, np.ndarray)))
         return real(*arrays, k_hashes=k_hashes)
 
+    def device_put(x, *args, **kw):
+        uploaded.append(np.asarray(x).nbytes)
+        return real_put(x, *args, **kw)
+
     monkeypatch.setattr(ops, "probe_pairs", probe_pairs)
+    monkeypatch.setattr(jax, "device_put", device_put)
     stats = db.tree.stats
     b0, c0 = stats["probe_h2d_bytes"], stats["probe_calls"]
     rng = np.random.default_rng(6)
     for _ in range(6):
         db.get_batch([int(k) for k in rng.choice(n, 8, replace=False)])
     assert handed and stats["probe_calls"] - c0 == len(handed)
-    assert stats["probe_h2d_bytes"] - b0 == sum(handed)
+    assert 0 < len(uploaded) < len(handed)
+    assert all(b == 4 * 256 * 4 for b in handed)
+    assert stats["probe_h2d_bytes"] - b0 == sum(handed) + sum(uploaded)
+
+
+def test_each_level_image_is_uploaded_once_while_its_membership_holds():
+    pytest.importorskip("jax")
+    db, n = _jax_db()
+    stats = db.tree.stats
+    rng = np.random.default_rng(8)
+    probed = set()
+    for _ in range(5):
+        batch = [int(k) for k in rng.choice(n, 8, replace=False)]
+        probed.update(_expected_levels(db.tree, batch)[1])
+        db.get_batch(batch)
+    assert stats["probe_calls"] > len(probed)
+    assert stats["probe_image_uploads"] == len(probed)
+
+
+def test_a_compaction_uploads_the_level_images_it_changed():
+    """After compactions change levels' membership, the next probe of each
+    changed level uploads its new image and frees the old one's device
+    copy; every answer matches the numpy route's, so no stale image
+    answers."""
+    pytest.importorskip("jax")
+    from repro.lsm import filters
+    dbs = [_jax_db(impl=impl)[0] for impl in ("jax", "numpy")]
+    tree = dbs[0].tree
+    batch = list(range(0, 1200, 25))
+    first = [db.get_batch(batch) for db in dbs]
+    assert first[0] == first[1]
+    old = {lvl: idx[4] for lvl, idx in tree._ridx.items()
+           if idx[4] is not None and len(idx[4])}
+    assert all(id(img) in filters._resident for img in old.values())
+    members = [list(ssts) for ssts in tree.levels]
+    for db in dbs:
+        rng = np.random.default_rng(10)
+        for i, k in enumerate(rng.integers(0, 1500, size=900)):
+            db.put(int(k), b"w%d" % i)
+        db.drain()
+    assert dbs[0].tree.stats["compactions"] > 0
+    moved = [l for l in old if tree.levels[l] != members[l]]
+    assert moved
+    # a changed level's old image leaves the device when the level changes
+    assert all(id(old[l]) not in filters._resident for l in moved)
+    uploads0 = tree.stats["probe_image_uploads"]
+    keys = list(range(0, 1600, 3))
+    answers = [db.get_batch(keys) for db in dbs]
+    assert answers[0] == answers[1]
+    assert tree.stats["probe_image_uploads"] - uploads0 >= len(
+        [l for l in moved if tree.levels[l]])
+    for lvl in moved:
+        if tree.levels[lvl]:
+            new = tree._ridx[lvl][4]
+            assert new is not old[lvl]
+            assert filters._resident[id(new)][0]() is new
 
 
 def test_numpy_route_counts_no_device_calls():
