@@ -12,7 +12,8 @@ The window submits each request when it is due on the wall clock
 (``chipbench.gen.chat_requests``) before each ``step()``; a token's time
 is the end of the step that produced it.  The mix's rate is above the
 engine's knee, so the queue stays full and the window ends at its close
-with requests still queued: tokens per second is the engine's capacity.
+with requests still queued: tokens per second is the engine's capacity,
+and the gaps between tokens are a per-layer tail.
 
 For the check, the harness reads the KV of each request that finishes in
 the window through the engine's own ``_gather_kv`` just before
@@ -25,6 +26,7 @@ lies, and (b) each layer's K and V.
 from __future__ import annotations
 
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import replace
 from typing import Dict, List
 
@@ -35,6 +37,8 @@ from chipbench.reference import dense_lm
 from chipbench.roofline import dense_layer
 
 WARMUP_RID = 10 ** 9
+# threads that compile the warm-up's programs while the next is lowered
+COMPILE_THREADS = 4
 
 
 def model_config(conf: Dict):
@@ -109,38 +113,53 @@ class _Rid:
         self.rid = rid
 
 
-def _warm_programs(st) -> None:
-    """Compile (or load from the cache) the layer program at every
-    (new tokens, resident length, residual dtype) and the HBM page gather
-    at every resident length the mix can reach."""
+def _warm_programs(st) -> int:
+    """Compile (or load from the cache) the layer program at every (new
+    tokens, resident length, residual dtype) and the HBM page gather at
+    every resident length the mix can reach; returns how many programs.
+    Each is lowered here, one after another, and compiled on a few
+    threads: the compiler runs outside the interpreter lock, the lowering
+    inside it."""
     import jax
     import jax.numpy as jnp
     from repro.serving import engine as E
     cfg, eng, t = st.cfg, st.eng, st.traffic
-    kv, hd, L = cfg.num_kv_heads, cfg.head_dim_, cfg.num_layers
     prompts = gen.warmup_lengths(t)
-    lengths = range(min(prompts), max(prompts) + t["output"]["max"] - 1)
-    shapes = [(p, 0) for p in prompts] + [(1, s) for s in lengths]
-    layers = st.params["layers"]
+    # the longest a sequence gets: its last token is served, not stored
+    max_length = max(prompts) + t["output"]["max"] - 1
+    kv, hd, L = cfg.num_kv_heads, cfg.head_dim_, cfg.num_layers
+    shape = jax.ShapeDtypeStruct
+    # resident lengths a decode sees, and a gather (the check's too, at
+    # release)
+    decoded = range(min(prompts), max_length)
+    gathered = range(min(prompts), max_length + 1)
+    li = shape((), jnp.int32)
 
-    def layer(shape, dtype):
-        tokens, resident = shape
-        pk = jnp.zeros((L, resident, kv, hd), jnp.float32)
-        pos = jnp.arange(tokens)[None] + resident
-        x = jnp.zeros((1, tokens, cfg.d_model), dtype)
-        jax.block_until_ready(E._layer_forward(cfg, layers, jnp.int32(0), x,
-                                               pos, pk, pk))
+    def layer(tokens, resident, dtype):
+        pk = shape((L, resident, kv, hd), jnp.float32)
+        x = shape((1, tokens, cfg.d_model), dtype)
+        return E._layer_forward.lower(cfg, st.params["layers"], li, x,
+                                      shape((1, tokens), jnp.int32), pk, pk)
 
     def gather(n):
-        # the engine builds the page index from a list, as here
-        idx = jnp.asarray(list(range(-(-n // eng.page_size))), jnp.int32)
-        jax.block_until_ready(E._take_pages_device(eng.hbm.k, idx, n))
+        pages = shape((-(-n // eng.page_size),), jnp.int32)
+        return E._take_pages_device.lower(eng.hbm.k, pages, n)
 
-    for shape in shapes:
-        for dtype in (jnp.bfloat16, jnp.float32):
-            layer(shape, dtype)
-    for n in lengths:
-        gather(n)
+    calls = [(layer, (tokens, resident, dtype))
+             for tokens, resident in [(p, 0) for p in prompts]
+             + [(1, s) for s in decoded]
+             for dtype in (jnp.bfloat16, jnp.float32)]
+    calls += [(gather, (n,)) for n in gathered]
+    with ThreadPoolExecutor(COMPILE_THREADS) as pool:
+        done = [pool.submit(lower(*args).compile) for lower, args in calls]
+        for f in done:
+            f.result()
+    # the engine makes each page index from a list: one conversion
+    # program per page count
+    page_counts = {-(-n // eng.page_size) for n in gathered}
+    for n in page_counts:
+        jax.block_until_ready(jnp.asarray(list(range(n)), jnp.int32))
+    return len(calls) + len(page_counts)
 
 
 def _warm_tier_copies(eng) -> None:
@@ -196,7 +215,7 @@ def setup(conf: Dict, traffic: Dict, seed: int):
     st.forwards, st.kv, st.capture = [], {}, False
     _watch(st)
     with harness.span("warm_programs"):
-        _warm_programs(st)
+        st.programs_warmed = _warm_programs(st)
     with harness.span("warm_engine"):
         _warm_tier_copies(st.eng)
         _warm_engine(st)
@@ -213,6 +232,8 @@ def window(st, seconds: float, tracer: harness.Tracer) -> None:
     times: Dict[int, List[float]] = {r["rid"]: [] for r in reqs}
     lateness, steps = [], []
     lead = max(0.0, (seconds - t["trace_seconds"]) / 2)
+    # the wall span the profiler holds the host, its start and stop included
+    profiled = []
     before = dict(eng.mgr.stats)
     nxt = 0
     t0 = time.perf_counter()
@@ -222,9 +243,11 @@ def window(st, seconds: float, tracer: harness.Tracer) -> None:
             break
         if tracer.enabled:
             if tracer.t0 is None and now >= lead:
+                profiled.append(now)
                 tracer.start()
             elif tracer.t1 is None and now >= lead + t["trace_seconds"]:
                 tracer.stop()
+                profiled.append(time.perf_counter() - t0)
         while nxt < len(reqs) and reqs[nxt]["due"] <= now:
             r = reqs[nxt]
             live[r["rid"]] = req = Request(rid=r["rid"], prompt=r["prompt"],
@@ -248,7 +271,11 @@ def window(st, seconds: float, tracer: harness.Tracer) -> None:
             if q.state == "done":
                 del live[rid]
     tracer.stop()
+    if len(profiled) == 1:
+        profiled.append(time.perf_counter() - t0)
+    st.profiled = profiled or None
     st.capture = False
+    st.pool_off_dtype = pool_off_dtype(eng, st.conf["engine"]["kv_dtype"])
     st.at_close = dict(eng.mgr.stats)
     st.before, st.times, st.steps = before, times, steps
     st.lateness, st.seconds, st.submitted = lateness, seconds, nxt
@@ -261,15 +288,21 @@ def window(st, seconds: float, tracer: harness.Tracer) -> None:
 
 
 def _end_to_end(st) -> Dict:
-    T = st.seconds
-    gaps, tokens = [], 0
+    tokens = sum(x <= st.seconds for ts in st.times.values() for x in ts)
+    return {"serve_tokens_per_s": tokens / st.seconds}
+
+
+def _itl_p95_ms(st):
+    """95th percentile of the gaps between each request's consecutive
+    tokens done by the close, leaving out gaps that overlap the span the
+    profiler held; None without a gap."""
+    skip = st.profiled
+    gaps = []
     for ts in st.times.values():
-        done = [x for x in ts if x <= T]
-        gaps += [b - a for a, b in zip(done, done[1:])]
-        tokens += len(done)
-    return {"serve_itl_p95_ms": 1e3 * harness.percentile(gaps, 95)
-            if gaps else float("nan"),
-            "serve_tokens_per_s": tokens / T}
+        done = [x for x in ts if x <= st.seconds]
+        gaps += [b - a for a, b in zip(done, done[1:])
+                 if skip is None or b <= skip[0] or a >= skip[1]]
+    return 1e3 * harness.percentile(gaps, 95) if gaps else None
 
 
 def _ttft(st) -> List[float]:
@@ -296,6 +329,7 @@ def _layer_context(st) -> Dict:
             - st.before["bytes_migrated"],
             "tokens_out": tokens},
         "model_flops": flops, "step_s": step_s,
+        "itl_p95_ms": _itl_p95_ms(st),
         "traced_layer_calls": [(n, s, L) for n, s in traced],
         "dims": dims,
     }
@@ -329,16 +363,19 @@ def reference_inputs(st):
 
 
 def reference_numbers(params, toks, sel, served, valid, dims, kv, rids,
-                      quant=None) -> Dict:
+                      quant=None, kv_dtype=None) -> Dict:
     """Worst served-token logit gap and worst per-layer relative K/V error
-    of the engine against the float32 reference (``quant``: the control,
+    of the engine against the float32 reference (``quant`` or
+    ``kv_dtype``: the control, weights or KV held below the configuration,
     whose first-ranked token stands in for the served one)."""
     import jax.numpy as jnp
     logits, ks, vs = dense_lm.forward(params, jnp.asarray(toks),
                                       jnp.asarray(sel), dims)
-    if quant is not None:
+    control = quant is not None or kv_dtype is not None
+    if control:
         c_logits, c_ks, c_vs = dense_lm.forward(params, jnp.asarray(toks),
-                                                jnp.asarray(sel), dims, quant)
+                                                jnp.asarray(sel), dims, quant,
+                                                kv_dtype)
         served = np.asarray(jnp.argmax(c_logits, -1))
         del c_logits
     best = np.asarray(jnp.max(logits, -1))
@@ -351,15 +388,42 @@ def reference_numbers(params, toks, sel, served, valid, dims, kv, rids,
         n = int(valid[b].sum()) + int(sel[b, 0])       # tokens with KV
         for name, ref in (("k", ks), ("v", vs)):
             r = ref[:, b, :n]
-            if quant is None:
-                e = kv[rid][0 if name == "k" else 1]
-            else:
+            if control:
                 e = (c_ks if name == "k" else c_vs)[:, b, :n]
-            num = jnp.sqrt(jnp.sum((e - r) ** 2, axis=(1, 2, 3)))
+            else:
+                e = kv[rid][0 if name == "k" else 1]
+            num = jnp.sqrt(jnp.sum((e.astype(jnp.float32) - r) ** 2,
+                                   axis=(1, 2, 3)))
             den = jnp.sqrt(jnp.sum(r ** 2, axis=(1, 2, 3)))
             errs.append(float(jnp.max(num / den)))
     return {"logit_gap": gap,
             "kv_rel_err": max(errs) if errs else float("nan")}
+
+
+def pool_off_dtype(eng, kv_dtype: str) -> int:
+    """How many of the engine's KV arrays (K and V of each tier) are not
+    held in the configuration's ``kv_dtype``: a pool in a lower type than
+    stated is another result, which the numbers against the reference
+    barely see, as bfloat16 rounding is smaller than the K/V error the
+    engine's own bf16 products make."""
+    return sum(str(a.dtype) != kv_dtype
+               for pool in (eng.hbm, eng.host) for a in (pool.k, pool.v))
+
+
+def checks_of(numbers: Dict, limits: Dict) -> List[Dict]:
+    """Each number ``correct`` compares, beside its limit: ``numbers``
+    holds ``reference_numbers``' two, ``kv_pool_off_dtype`` and the count
+    of ``requests`` checked."""
+    return [
+        {"name": "served_logit_gap", "value": numbers["logit_gap"],
+         "limit": limits["served_logit_gap"]},
+        {"name": "kv_rel_err", "value": numbers["kv_rel_err"],
+         "limit": limits["kv_rel_err"]},
+        {"name": "kv_pool_off_dtype", "value": numbers["kv_pool_off_dtype"],
+         "limit": 0},
+        {"name": "no_finished_request", "value": int(not numbers["requests"]),
+         "limit": 0},
+    ]
 
 
 def finish(st) -> Dict:
@@ -369,6 +433,7 @@ def finish(st) -> Dict:
     info = {
         "requests_submitted": st.submitted, "requests_finished": len(st.done),
         "requests_in_flight_at_close": st.in_flight,
+        "itl_p95_ms": layer["itl_p95_ms"],
         "ttft_p50_s": harness.percentile(ttft, 50) if ttft else None,
         "ttft_p95_s": harness.percentile(ttft, 95) if ttft else None,
         "generator_late_p95_s": harness.percentile(st.lateness, 95)
@@ -379,18 +444,13 @@ def finish(st) -> Dict:
         "warmup_demotions": st.warm_stats["demotions"],
         "warmup_promotions": st.warm_stats["promotions"],
         "steps": len(st.steps), "forwards": len(st.forwards),
+        "programs_warmed": st.programs_warmed,
     }
     toks, sel, served, valid, rids = reference_inputs(st)
     ref = reference_numbers(st.params, toks, sel, served, valid, st.dims,
                             st.kv, rids)
-    limits = st.traffic["limits"]
-    checks = [
-        {"name": "served_logit_gap", "value": ref["logit_gap"],
-         "limit": limits["served_logit_gap"]},
-        {"name": "kv_rel_err", "value": ref["kv_rel_err"],
-         "limit": limits["kv_rel_err"]},
-        {"name": "no_finished_request", "value": int(not rids), "limit": 0},
-    ]
+    checks = checks_of(dict(ref, kv_pool_off_dtype=st.pool_off_dtype,
+                            requests=len(rids)), st.traffic["limits"])
     info.update(checked_tokens=int(valid.sum()), checked_requests=len(rids))
     return {"end_to_end": e2e, "layer": layer, "checks": checks,
             "attempted": st.submitted, "failed": 0, "info": info}

@@ -35,9 +35,17 @@ def load_module(path: Path):
     return mod
 
 
-def load_benchmark() -> Dict:
+def load_benchmark(pending: bool = False) -> Dict:
+    """``BENCHMARK.json``; with ``pending``, the entries of each file
+    under ``chipbench/pending`` appended, as the PR that admits them
+    would append them (for the rehearsal and the readings of limits)."""
     with open(ROOT / "BENCHMARK.json") as f:
-        return json.load(f)
+        bench = json.load(f)
+    for path in sorted((HERE / "pending").glob("*.json")) if pending else ():
+        part = json.loads(path.read_text())
+        for key in ("configs", "workloads", "end_to_end", "per_layer"):
+            bench[key] = bench[key] + part[key]
+    return bench
 
 
 class Cell:

@@ -1,6 +1,8 @@
-"""Bytes one device probe call hands the device, padding included: the
+"""Bytes handed to the device per probe call, padding included: the
 difference over the window of the tree's ``probe_h2d_bytes`` counter over
-that of ``probe_calls`` (``repro.lsm.filters.padded_bytes`` per call)."""
+that of ``probe_calls``.  The counter adds each call's padded pairs and
+each level image when it is uploaded to stay on the device, so the
+images' share is spread over the calls that reuse them."""
 
 
 def read(ctx):

@@ -6,9 +6,11 @@
 
 ``serve``: per seed, the cell is set up and driven for a short window at
 its own load; then the numbers a run compares are read for the program
-(served tokens and KV against the float32 reference) and for the
-controls: the reference with its weights rounded to int8 and to float8,
-whose first-ranked token and K/V stand in for the program's.
+(served tokens and KV against the float32 reference, the pool's type)
+and for the controls: the reference with its weights rounded to int8 and
+to float8, and with its K/V held in bfloat16 below the configuration's
+float32, whose first-ranked token and K/V stand in for the program's.
+Each row says whether the run's own comparison passes it (``correct``).
 
 ``store``: per seed, the cell runs a short window with the control in the
 program's place: a device probe that answers "absent" for one pair in
@@ -30,7 +32,9 @@ sys.path[:0] = [str(_HERE.parent), str(_HERE.parent / "src")]
 
 from chipbench import harness  # noqa: E402
 
-QUANTS = ("int8", "fp8")
+# each control: its side's name, the weights' rounding, the KV's type
+CONTROLS = (("control_int8", "int8", None), ("control_fp8", "fp8", None),
+            ("control_bf16_kv", None, "bfloat16"))
 
 
 def serve_readings(cell: harness.Cell, seed: int, seconds: float) -> list:
@@ -38,15 +42,19 @@ def serve_readings(cell: harness.Cell, seed: int, seconds: float) -> list:
     st = drv.setup(cell.config, cell.traffic, seed)
     drv.window(st, seconds, harness.Tracer(False))
     toks, sel, served, valid, rids = drv.reference_inputs(st)
-    rows = [dict(side="program", seed=seed, requests=len(rids),
-                 tokens=int(valid.sum()),
-                 **drv.reference_numbers(st.params, toks, sel, served, valid,
-                                         st.dims, st.kv, rids))]
-    for q in QUANTS:
-        rows.append(dict(side=f"control_{q}", seed=seed,
-                         **drv.reference_numbers(st.params, toks, sel, served,
-                                                 valid, st.dims, st.kv, rids,
-                                                 quant=q)))
+    stated = cell.config["engine"]["kv_dtype"]
+    rows = []
+    for side, q, kv in (("program", None, None),) + CONTROLS:
+        # the engine's pools, or the control's K and V held in its type
+        off = (st.pool_off_dtype if side == "program"
+               else 2 * (kv not in (None, stated)))
+        nums = dict(drv.reference_numbers(st.params, toks, sel, served, valid,
+                                          st.dims, st.kv, rids, quant=q,
+                                          kv_dtype=kv),
+                    kv_pool_off_dtype=off, requests=len(rids))
+        checks = drv.checks_of(nums, cell.traffic["limits"])
+        rows.append(dict(side=side, seed=seed, tokens=int(valid.sum()),
+                         correct=harness.all_within(checks), **nums))
     return rows
 
 
@@ -90,7 +98,7 @@ def main(argv=None) -> int:
     enable_compile_cache()
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    bench = harness.load_benchmark()
+    bench = harness.load_benchmark(pending=True)
     for seed in [int(s) for s in args.seeds.split(",")]:
         if args.what == "serve":
             cell = harness.Cell(bench, "serve-chat-tiered")

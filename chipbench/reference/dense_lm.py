@@ -7,6 +7,9 @@ computes every product at the highest matmul precision.
 ``quant`` makes the control: the same forward with every weight matrix
 first rounded to int8 (symmetric, one scale per output channel) or to
 float8 e4m3 (one scale per output channel), and computed as above.
+``kv_dtype`` makes the control of the KV pool: each layer's keys and
+values held in that type (bfloat16 below the configuration's float32),
+attended from it and returned in it.
 """
 from __future__ import annotations
 
@@ -73,8 +76,9 @@ def _mm(a, b):
     return jnp.matmul(a, b, precision=HIGHEST)
 
 
-@functools.partial(jax.jit, static_argnames=("dims", "quant"))
-def forward(params, tokens, sel, dims: Dims, quant: Optional[str] = None):
+@functools.partial(jax.jit, static_argnames=("dims", "quant", "kv_dtype"))
+def forward(params, tokens, sel, dims: Dims, quant: Optional[str] = None,
+            kv_dtype: Optional[str] = None):
     """``tokens`` [B, S] from position 0.  Returns the logits at positions
     ``sel`` [B, R] ([B, R, vocab]) and every layer's post-RoPE keys and
     values ([layers, B, S, kv_heads, head_dim] each)."""
@@ -93,6 +97,9 @@ def forward(params, tokens, sel, dims: Dims, quant: Optional[str] = None):
         v = _mm(y, _round(a["wv"], quant, -1)).reshape(b, s, kv, hd)
         q = _rope(_norm(q, a["q_norm"], dims.eps), dims.rope_theta)
         k = _rope(_norm(k, a["k_norm"], dims.eps), dims.rope_theta)
+        held = (k, v) if kv_dtype is None else (k.astype(kv_dtype),
+                                                v.astype(kv_dtype))
+        k, v = (t.astype(jnp.float32) for t in held)
         qg = q.reshape(b, s, kv, g, hd)
         scores = jnp.einsum("bskgd,btkd->bkgst", qg, k,
                             precision=HIGHEST) / jnp.sqrt(jnp.float32(hd))
@@ -104,7 +111,7 @@ def forward(params, tokens, sel, dims: Dims, quant: Optional[str] = None):
         y = _norm(x, p["mlp_norm"], dims.eps)
         act = jax.nn.silu(_mm(y, _round(m["w_gate"], quant, -1))) \
             * _mm(y, _round(m["w_up"], quant, -1))
-        return x + _mm(act, _round(m["w_down"], quant, -1)), (k, v)
+        return x + _mm(act, _round(m["w_down"], quant, -1)), held
 
     x, (ks, vs) = jax.lax.scan(layer, x, params["layers"])
     rows = jnp.take_along_axis(x, sel[..., None], axis=1)

@@ -47,18 +47,6 @@ def tiny_scenario():
     return d
 
 
-def bench_with_pending():
-    """``BENCHMARK.json`` with the cells under ``chipbench/pending`` added,
-    as a later PR would add them."""
-    import json
-    bench = harness.load_benchmark()
-    for path in sorted((harness.HERE / "pending").glob("*.json")):
-        part = json.loads(path.read_text())
-        for key in ("configs", "workloads", "end_to_end", "per_layer"):
-            bench[key] = bench[key] + part[key]
-    return bench
-
-
 def tiny_store_cell(objects=2000):
     cell = harness.Cell(harness.load_benchmark(), "store-ycsb-c")
     cell.config = dict(cell.config, objects=objects, scenario=tiny_scenario())
@@ -69,7 +57,8 @@ def tiny_store_cell(objects=2000):
 
 
 def smoke_serve_cell(rate=4.0):
-    cell = harness.Cell(bench_with_pending(), "serve-chat-tiered")
+    cell = harness.Cell(harness.load_benchmark(pending=True),
+                        "serve-chat-tiered")
     conf = copy.deepcopy(cell.config)
     conf["registry_name"] = "qwen3-1.7b-smoke"
     conf["hf_config"].update(num_hidden_layers=2, hidden_size=64,

@@ -8,7 +8,6 @@ import sys
 from pathlib import Path
 
 from chipbench import harness
-from conftest import bench_with_pending
 
 ROOT = Path(__file__).resolve().parents[2]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -18,7 +17,7 @@ def test_every_part_is_found_by_name():
     assert set(harness.load_benchmark()) == {
         "command", "paths", "run_seconds", "configs", "workloads",
         "end_to_end", "per_layer"}
-    bench = bench_with_pending()
+    bench = harness.load_benchmark(pending=True)
     for c in bench["configs"]:
         assert NAME.match(c["name"]) and (ROOT / c["file"]).is_file()
         assert all(NAME.match(k) for k in c["reduced"])

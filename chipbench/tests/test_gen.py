@@ -3,12 +3,11 @@ order."""
 import numpy as np
 
 from chipbench import gen
-from chipbench.harness import Cell
-from conftest import bench_with_pending
+from chipbench.harness import Cell, load_benchmark
 
 
 def _mix():
-    return Cell(bench_with_pending(), "serve-chat-tiered").traffic
+    return Cell(load_benchmark(pending=True), "serve-chat-tiered").traffic
 
 
 def test_seeds_share_lengths_and_gaps():

@@ -132,8 +132,12 @@ def test_traced_store_run_reads_program_spans(capsys):
     res, info = json.loads(lines[-1]), json.loads(lines[-2])["info"]
     assert res["correct"] is True
     m = {k: v["value"] for k, v in res["metrics"].items()}
-    # the jax route on the CPU pads to at least the smallest buckets
-    assert m["store_probe_h2d_bytes_per_call"] >= 16 * 256 + 4 * 1024
+    # each call hands over its pairs, padded to at least the smallest
+    # bucket (four 256-entry arrays of 4 B); a level image moves only when
+    # it is uploaded to stay on the device, less than 4 KiB a call on
+    # average here
+    assert 16 * 256 <= m["store_probe_h2d_bytes_per_call"] \
+        <= 16 * 256 + 4 * 1024
     assert m["store_probe_pad_ms"] > 0
     assert 0 < m["store_des_self_share"] < 100
     assert 0 <= m["store_background_share"] < 100

@@ -69,10 +69,17 @@ def test_store_fault_is_not_correct(fault, monkeypatch, capsys):
 
 
 def test_serve_cell_is_correct(capsys):
-    res = _run(smoke_serve_cell(), capsys, seconds=3.0, trace=True)
+    rc = run.run_cell(smoke_serve_cell(), 2 ** 31 + 11, 3.0, True,
+                      require_chip=False, peaks=FAKE_PEAKS)
+    assert rc == 0
+    lines = capsys.readouterr().out.strip().splitlines()
+    res, info = json.loads(lines[-1]), json.loads(lines[-2])["info"]
     assert res["correct"] is True, res["checks"]
+    # set-up warmed every program the window used
+    assert info["programs_warmed"] > 0 and info["compiles_in_window"] == 0
     m = res["metrics"]
     assert m["serve_mfu"]["value"] > 0
+    assert m["serve_itl_p95_ms"]["value"] > 0
     assert "serve_kv_migrated_bytes_per_token" in m
     assert "layer_forward_roofline" not in m
 
@@ -111,10 +118,26 @@ def _drop_kv_writes(monkeypatch):
     monkeypatch.setattr(PagedPool, "write_token", write)
 
 
+def _bf16_kv_pool(monkeypatch):
+    import jax.numpy as jnp
+    from repro.serving.paged_kv import PagedPool
+    real = PagedPool.__init__
+
+    def init(self, *a, **kw):
+        real(self, *a, **kw)
+        if self.materialize:
+            self.k, self.v = (t.astype(jnp.bfloat16) for t in (self.k, self.v))
+
+    monkeypatch.setattr(PagedPool, "__init__", init)
+
+
 @pytest.mark.parametrize("fault", [_zero_host_page, _alter_tokens,
-                                   _drop_kv_writes])
+                                   _drop_kv_writes, _bf16_kv_pool])
 def test_serve_fault_is_not_correct(fault, monkeypatch, capsys):
     fault(monkeypatch)
     cell = smoke_serve_cell(rate=8.0)
+    # two HBM zones besides the prefix cache's, so that the window demotes
+    # a sequence to the host under any request order
+    cell.config["engine"]["hbm_zones"] = 3
     res = _run(cell, capsys, seconds=3.0)
     assert res["correct"] is False, res["checks"]
