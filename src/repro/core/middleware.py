@@ -30,6 +30,9 @@ if TYPE_CHECKING:
 
 SSD, HDD = "ssd", "hdd"
 _CHUNK = int(1 * MiB)
+# the write streams whose bytes ``stats`` counts, each also under the
+# device it lands on (``write_bytes.<stream>``, ``write_bytes.<device>``)
+WRITE_STREAMS = ("wal", "flush", "compaction", "migration")
 
 
 class HybridZonedBackend:
@@ -99,6 +102,8 @@ class HybridZonedBackend:
 
         # ---- stats -------------------------------------------------------
         self.stats = defaultdict(float)
+        for key in WRITE_STREAMS + (SSD, HDD):
+            self.stats["write_bytes." + key] = 0.0
 
     def start(self) -> None:
         self.placement.start()
@@ -160,19 +165,29 @@ class HybridZonedBackend:
             z.owner = owner
         return zones
 
+    def count_write(self, stream: str, dev: ZonedDevice, nbytes: int) -> None:
+        """Bytes of one write stream that reached ``dev``."""
+        self.stats["write_bytes." + stream] += nbytes
+        self.stats["write_bytes." + (SSD if dev is self.ssd else HDD)] += nbytes
+
     def write_sst(self, sst: "SST", source: str):
-        """Generator: place (per policy) and sequentially write a new SST."""
-        tier = self.placement.choose_tier(sst.level, source)
-        zones = self.alloc_sst_zones(tier, sst.size_bytes, f"sst:{sst.sid}")
-        if zones is None and tier == SSD:
-            tier = HDD
-            zones = self.alloc_sst_zones(HDD, sst.size_bytes, f"sst:{sst.sid}")
-        if zones is None:
-            raise RuntimeError("HDD out of zones — size the simulation larger")
-        sst.tier = tier
-        sst.zones = zones
-        sst.birth = self.sim.now
-        self._register(sst)
+        """Generator: place (per policy) and sequentially write a new SST
+        (``source`` is its write stream: ``flush`` or ``compaction``)."""
+        with span("placement"):
+            tier = self.placement.choose_tier(sst.level, source)
+            zones = self.alloc_sst_zones(tier, sst.size_bytes,
+                                         f"sst:{sst.sid}")
+            if zones is None and tier == SSD:
+                tier = HDD
+                zones = self.alloc_sst_zones(HDD, sst.size_bytes,
+                                             f"sst:{sst.sid}")
+            if zones is None:
+                raise RuntimeError(
+                    "HDD out of zones — size the simulation larger")
+            sst.tier = tier
+            sst.zones = zones
+            sst.birth = self.sim.now
+            self._register(sst)
         # lock while the write streams: the SST is registered (placement
         # must see its zones as allocated) but the migrator must not move
         # a half-written SST
@@ -180,14 +195,16 @@ class HybridZonedBackend:
         try:
             yield from self._stream_to_zones(
                 self.device_of(tier), list(zones), sst.size_bytes,
-                tag=f"L{sst.level}")
+                tag=f"L{sst.level}", stream=source)
         finally:
             sst.locked = False
 
     def _stream_to_zones(self, dev: ZonedDevice, zones: List[Zone],
-                         total: int, tag: str, background: bool = False):
+                         total: int, tag: str, background: bool = False,
+                         stream: Optional[str] = None):
         """Generator: sequentially append ``total`` bytes across ``zones``
-        in ``io_chunk``-sized requests (shared by SST writes and repairs)."""
+        in ``io_chunk``-sized requests (shared by SST writes and repairs),
+        counted under ``stream``, when given, once the whole is written."""
         done = 0
         zi = 0
         while done < total:
@@ -202,6 +219,8 @@ class HybridZonedBackend:
                 yield dev.append(zone, take, tag=tag, background=background)
                 rem -= take
             done += n
+        if stream is not None:
+            self.count_write(stream, dev, total)
 
     def delete_sst(self, sst: "SST") -> None:
         """SST removed by compaction: reset its zones (space reclaim)."""
@@ -215,19 +234,22 @@ class HybridZonedBackend:
         self._wake_wal_waiters()
 
     def relocate(self, sst: "SST", new_tier: str, new_zones: List[Zone]) -> None:
-        """Migration finished: flip tiers, reset source zones."""
-        old_dev = self.device_of(sst.tier)
-        for z in sst.zones:
-            old_dev.reset_zone(z)
-        if sst.tier == SSD:
-            self._ssd_level_counts[sst.level] -= 1
-        sst.tier = new_tier
-        sst.zones = new_zones
-        if new_tier == SSD:
-            self._ssd_level_counts[sst.level] += 1
-            # cached copies of now-SSD-resident blocks are redundant
-            if self.cache is not None:
-                self.cache.drop_sst(sst.sid)
+        """Migration (or a repair) finished: flip tiers, reset source
+        zones, then wake the writers waiting for a zone (outside the span:
+        waking resumes them)."""
+        with span("migration.move"):
+            old_dev = self.device_of(sst.tier)
+            for z in sst.zones:
+                old_dev.reset_zone(z)
+            if sst.tier == SSD:
+                self._ssd_level_counts[sst.level] -= 1
+            sst.tier = new_tier
+            sst.zones = new_zones
+            if new_tier == SSD:
+                self._ssd_level_counts[sst.level] += 1
+                # cached copies of now-SSD-resident blocks are redundant
+                if self.cache is not None:
+                    self.cache.drop_sst(sst.sid)
         self._wake_wal_waiters()
 
     def note_level_change(self, sst: "SST", new_level: int) -> None:
@@ -517,18 +539,20 @@ class HybridZonedBackend:
                 # freed by flushing data the batch itself still holds.
                 # Basic schemes can spill the WAL to HDD zones (smaller),
                 # so bound by the smallest device that may host it.
-                if self.placement.reserves_wal:
-                    cap = max(self.ssd.zone_capacity, 1)
-                else:
-                    cap = max(min(self.ssd.zone_capacity,
-                                  self.hdd.zone_capacity), 1)
-                batch: List[tuple] = []
-                total = 0
-                while self._wal_queue and \
-                        (not batch or total + self._wal_queue[0][0] <= cap):
-                    n, ev = self._wal_queue.popleft()
-                    batch.append((n, ev))
-                    total += n
+                with span("wal.append"):
+                    if self.placement.reserves_wal:
+                        cap = max(self.ssd.zone_capacity, 1)
+                    else:
+                        cap = max(min(self.ssd.zone_capacity,
+                                      self.hdd.zone_capacity), 1)
+                    batch: List[tuple] = []
+                    total = 0
+                    while self._wal_queue and (
+                            not batch
+                            or total + self._wal_queue[0][0] <= cap):
+                        n, ev = self._wal_queue.popleft()
+                        batch.append((n, ev))
+                        total += n
                 touched = []
                 while total > 0:
                     rec = self._cur_wal
@@ -549,7 +573,9 @@ class HybridZonedBackend:
                     if rec not in touched:
                         touched.append(rec)
                     yield rec["dev"].append(rec["zone"], take, tag="wal")
+                    self.count_write("wal", rec["dev"], take)
                     total -= take
+                # no span here: each acknowledgement resumes its writer
                 for _, ev in batch:
                     ev.succeed(touched)
         finally:

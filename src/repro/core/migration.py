@@ -165,7 +165,9 @@ class Migrator:
         sst.migrating = True
         new_zones = None
         try:
-            new_zones = be.alloc_sst_zones(dst, sst.size_bytes, f"sst:{sst.sid}")
+            with span("migration.move"):
+                new_zones = be.alloc_sst_zones(dst, sst.size_bytes,
+                                               f"sst:{sst.sid}")
             if new_zones is None:
                 return False
             src_dev = be.device_of(sst.tier)
@@ -192,6 +194,7 @@ class Migrator:
                         zi += 1
                         continue
                     yield dst_dev.append(zone, take, tag="migr", background=True)
+                    be.count_write("migration", dst_dev, take)
                     rem -= take
                 done += n
                 self.bytes_moved += n

@@ -136,6 +136,8 @@ class DB:
         self.admission.debt_gauge = lambda: float(self.tree.compaction_debt())
         self._crashed = False
         self.recovery: Optional[dict] = None   # stats of the last reopen()
+        # sequence of the last stamped write (repro.workloads.ycsb.stamp)
+        self.write_seq = 0
         # telemetry bus (repro.obs): off by default; telemetry=True attaches
         # a MetricsRegistry at the default sample period, a float sets the
         # period in virtual seconds

@@ -2,8 +2,10 @@
 
 Keys are uint64 ranks held in sorted numpy arrays (compact and fast to merge
 with vectorised numpy); per-key tombstone bits support deletes.  Values are
-optionally materialised (correctness tests / the quickstart example run with
-``store_values=True``; large benchmark runs track sizes only).
+optionally materialised (``store_values=True``: correctness tests, the
+quickstart example and the versioned benchmark cell) as an object array
+aligned with the keys, so a merge carries them with the same permutation
+as the keys; otherwise the store tracks sizes only.
 
 Each SST carries a Bloom filter in one of two modes (``LSMConfig.filters``):
 
@@ -20,7 +22,7 @@ Each SST carries a Bloom filter in one of two modes (``LSMConfig.filters``):
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import List, Optional
 
 import numpy as np
 
@@ -38,13 +40,18 @@ def _mix64(x: np.ndarray | int) -> np.ndarray | int:
 
 
 def merge_runs(runs_newest_first: List[np.ndarray],
-               tombs_newest_first: List[np.ndarray]):
+               tombs_newest_first: List[np.ndarray],
+               values_newest_first: Optional[List[np.ndarray]] = None):
     """Merge sorted key runs, newest first; newest version of each key wins.
 
-    Returns (keys, tombstones) sorted ascending, deduplicated.
+    Returns (keys, tombstones, values) sorted ascending, deduplicated;
+    values are the winning versions' (``values_newest_first`` holds arrays
+    aligned with the runs), or None without them.
     """
     if not runs_newest_first:
-        return (np.empty(0, np.uint64), np.empty(0, np.bool_))
+        return (np.empty(0, np.uint64), np.empty(0, np.bool_),
+                None if values_newest_first is None
+                else np.empty(0, dtype=object))
     keys = np.concatenate(runs_newest_first)
     tombs = np.concatenate(tombs_newest_first)
     # stable sort keeps newest-first order among equal keys
@@ -53,7 +60,10 @@ def merge_runs(runs_newest_first: List[np.ndarray],
     tombs = tombs[order]
     first = np.ones(len(keys), dtype=bool)
     first[1:] = keys[1:] != keys[:-1]
-    return keys[first], tombs[first]
+    values = None
+    if values_newest_first is not None:
+        values = np.concatenate(values_newest_first)[order][first]
+    return keys[first], tombs[first], values
 
 
 @dataclass
@@ -70,7 +80,7 @@ class SST:
     num_reads: int = 0
     locked: bool = False                  # selected by a running compaction
     migrating: bool = False               # being moved between tiers
-    values: Optional[Dict[int, bytes]] = None
+    values: Optional[np.ndarray] = None   # object array aligned with keys
     # real Bloom filter (filters="real"): packed uint32 bit array + probe
     # count, built by repro.lsm.filters.attach_filter; None under the
     # injected-FP oracle mode

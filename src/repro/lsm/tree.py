@@ -124,7 +124,9 @@ class LSMTree:
         self.compaction_pace = 1.0
         self.block_cache = BlockCache(cfg.block_cache_blocks, self._on_evict)
         self.stats: Dict[str, float] = {
-            "puts": 0, "gets": 0, "hits": 0, "scans": 0,
+            # puts entered and puts acknowledged (in the memtable, after
+            # their WAL record is durable)
+            "puts": 0, "puts_acked": 0, "gets": 0, "hits": 0, "scans": 0,
             "write_stalls": 0, "compactions": 0, "flushes": 0,
             "bloom_fp": 0, "filter_probes": 0, "delayed_writes": 0,
             # device probe calls, the level images uploaded to stay on the
@@ -295,28 +297,31 @@ class LSMTree:
                 self.stats["delayed_writes"] += 1
                 yield target - self.sim.now   # bare-delay: no Event
         wal_recs = yield from self.backend.wal_append(self.cfg.obj_size)
-        stored = value if self.cfg.store_values else None
-        mt = self.memtable
-        mt.data[key] = (tombstone, stored)
-        mt.writes += 1
-        if tenant is not None:
-            mt.tenant_objs[tenant] = mt.tenant_objs.get(tenant, 0) + 1
-        # attribute the WAL bytes (and the logical record, for crash
-        # replay) to the generation the data actually landed in (the
-        # memtable may have rotated while queued)
-        self.backend.wal_attribute(wal_recs, mt.gen, key=key,
-                                   tomb=tombstone, value=stored,
-                                   tenant=tenant)
-        if len(self.memtable) >= self.cfg.memtable_max_objs:
-            self._rotate_memtable()
+        with span("put"):
+            stored = value if self.cfg.store_values else None
+            mt = self.memtable
+            mt.data[key] = (tombstone, stored)
+            mt.writes += 1
+            if tenant is not None:
+                mt.tenant_objs[tenant] = mt.tenant_objs.get(tenant, 0) + 1
+            # attribute the WAL bytes (and the logical record, for crash
+            # replay) to the generation the data actually landed in (the
+            # memtable may have rotated while queued)
+            self.backend.wal_attribute(wal_recs, mt.gen, key=key,
+                                       tomb=tombstone, value=stored,
+                                       tenant=tenant)
+            if len(self.memtable) >= self.cfg.memtable_max_objs:
+                self._rotate_memtable()
+            self.stats["puts_acked"] += 1
 
     def delete(self, key: int) -> Generator:
         yield from self.put(key, tombstone=True)
 
     def _rotate_memtable(self) -> None:
-        self.immutables.append(self.memtable)
-        self.memtable = MemTable(gen=self.memtable.gen + 1)
-        self._kick_background()
+        with span("memtable.rotate"):
+            self.immutables.append(self.memtable)
+            self.memtable = MemTable(gen=self.memtable.gen + 1)
+            self._kick_background()
 
     # ==================================================================
     # flush
@@ -363,21 +368,8 @@ class LSMTree:
                 # flush; without this, gets in flight miss these keys)
                 self._flushing = batch
                 gens = {m.gen for m in batch}
-                runs, tombs, values = [], [], {}
                 with span("flush.merge"):
-                    for m in reversed(batch):   # newest first
-                        ks = np.fromiter(m.data.keys(), dtype=np.uint64,
-                                         count=len(m.data))
-                        order = np.argsort(ks, kind="stable")
-                        ks = ks[order]
-                        tb = np.fromiter((m.data[int(k)][0] for k in ks),
-                                         dtype=np.bool_, count=len(ks))
-                        runs.append(ks)
-                        tombs.append(tb)
-                        if self.cfg.store_values:
-                            for k, (t, v) in m.data.items():
-                                values.setdefault(k, v)
-                    keys, tb = merge_runs(runs, tombs)
+                    keys, tb, values = self._merge_memtables(batch)
                 # flush->SST lineage: the batch's per-tenant write-volume
                 # shares become each output SST's tenant byte composition
                 tally: Dict[str, int] = {}
@@ -388,8 +380,8 @@ class LSMTree:
                         tally[t] = tally.get(t, 0) + c
                 comp = ({t: c / writes for t, c in tally.items()}
                         if writes > 0 else {})
-                for ks, tbs in self._split_sst(keys, tb):
-                    sst = self._make_sst(ks, tbs, level=0, values=values)
+                for ks, tbs, vs in self._split_sst(keys, tb, values):
+                    sst = self._make_sst(ks, tbs, level=0, values=vs)
                     if comp:
                         sst.tenant_bytes = {
                             t: f * sst.size_bytes for t, f in comp.items()}
@@ -409,21 +401,42 @@ class LSMTree:
                 ev.succeed()
         self._kick_background()
 
-    def _split_sst(self, keys: np.ndarray, tombs: np.ndarray):
+    def _merge_memtables(self, batch: List[MemTable]):
+        """One flush's merged run: (keys, tombs, values), the newest
+        memtable's version winning; values None unless the tree stores
+        them, else an object array aligned with the keys."""
+        runs, tombs, vals = [], [], []
+        for m in reversed(batch):   # newest first
+            ks = np.fromiter(m.data.keys(), dtype=np.uint64,
+                             count=len(m.data))
+            order = np.argsort(ks, kind="stable")
+            ks = ks[order]
+            tb = np.fromiter((m.data[int(k)][0] for k in ks),
+                             dtype=np.bool_, count=len(ks))
+            runs.append(ks)
+            tombs.append(tb)
+            if self.cfg.store_values:
+                # dict order is the keys' order above, before the sort
+                v = np.empty(len(m.data), dtype=object)
+                v[:] = [val for _, val in m.data.values()]
+                vals.append(v[order])
+        return merge_runs(runs, tombs,
+                          vals if self.cfg.store_values else None)
+
+    def _split_sst(self, keys: np.ndarray, tombs: np.ndarray,
+                   values: Optional[np.ndarray] = None):
         n = self.cfg.sst_max_objs
         for i in range(0, len(keys), n):
-            yield keys[i:i + n], tombs[i:i + n]
+            yield (keys[i:i + n], tombs[i:i + n],
+                   None if values is None else values[i:i + n])
 
     def _make_sst(self, keys: np.ndarray, tombs: np.ndarray, level: int,
-                  values: Optional[dict] = None) -> SST:
+                  values: Optional[np.ndarray] = None) -> SST:
         with span("sst.build"):
-            vals = None
-            if self.cfg.store_values and values is not None:
-                vals = {int(k): values.get(int(k)) for k in keys}
             sst = SST(sid=self._new_sst_id(), level=level, keys=keys,
                       tombs=tombs, obj_size=self.cfg.obj_size,
                       block_size=self.cfg.block_size, birth=self.sim.now,
-                      values=vals)
+                      values=values if self.cfg.store_values else None)
             if self.cfg.filters == "real":
                 filters.attach_filter(sst, self.cfg.filter_bits_per_key)
         return sst
@@ -513,10 +526,10 @@ class LSMTree:
             with span("compaction.merge"):
                 keys, tombs, values, comp = self._merge_inputs(level, inputs)
             outputs: List[SST] = []
-            for ks, tbs in self._split_sst(keys, tombs):
+            for ks, tbs, vs in self._split_sst(keys, tombs, values):
                 if not len(ks):
                     continue
-                sst = self._make_sst(ks, tbs, level=target, values=values)
+                sst = self._make_sst(ks, tbs, level=target, values=vs)
                 if comp:
                     sst.tenant_bytes = {
                         t: f * sst.size_bytes for t, f in comp.items()}
@@ -565,21 +578,17 @@ class LSMTree:
         dst_lvl = [s for s in inputs if s.level == target]
         ordered = (sorted(src_lvl, key=lambda s: -s.birth) + dst_lvl
                    if level == 0 else src_lvl + dst_lvl)
-        keys, tombs = merge_runs([s.keys for s in ordered],
-                                 [s.tombs for s in ordered])
-        values = None
-        if self.cfg.store_values:
-            values = {}
-            for s in ordered:
-                if s.values:
-                    for k, v in s.values.items():
-                        values.setdefault(k, v)
+        keys, tombs, values = merge_runs(
+            [s.keys for s in ordered], [s.tombs for s in ordered],
+            [s.values for s in ordered] if self.cfg.store_values else None)
         # drop tombstones when compacting into the last populated level
         bottom = all(not self.levels[l] for l in
                      range(target + 1, len(self.levels)))
         if bottom and len(keys):
             keep = ~tombs
             keys, tombs = keys[keep], tombs[keep]
+            if values is not None:
+                values = values[keep]
         # compaction lineage: outputs inherit the inputs' pooled
         # tenant byte composition, scaled to each output's size
         in_attr: Dict[str, float] = {}
@@ -679,7 +688,7 @@ class LSMTree:
             if bool(sst.tombs[idx]):
                 return (False, None)
             self.stats["hits"] += 1
-            val = sst.values.get(key) if sst.values else None
+            val = sst.values[idx] if sst.values is not None else None
             return (True, val)
         self.stats["bloom_fp"] += 1
         return None

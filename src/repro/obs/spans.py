@@ -5,8 +5,9 @@
 and writes them at ``stop_trace``, on the host plane of the xplane, on the
 clock the device events use.  So a reduction of the trace can put each of
 the device's idle gaps down to the innermost span the host was in.  When
-no trace runs, a span costs about a microsecond (jaxlib checks whether the
-profiler is on before it records anything); there is no other switch.
+no trace runs, ``span`` hands back one shared null context after a single
+check of jaxlib's profiler state, so the spans on the store's per-put
+path cost its load next to nothing; there is no other switch.
 
 The DES processes are generators, so a span covers only a synchronous
 section and never stays open across a ``yield``: a span left open while
@@ -25,28 +26,28 @@ PREFIX = "hhzs:"
 
 _NULL = contextlib.nullcontext()
 _annotation = None
+_recording = None     # ``TraceAnnotation.is_enabled`` once jax is loaded
 
 
-def _null(name, **args):
-    return _NULL
-
-
-def _resolve():
-    """``TraceAnnotation`` once jax is loaded, else a maker of the shared
-    null context.  Only a process that imported jax can run the profiler,
+def _resolve() -> bool:
+    """Whether the profiler records, resolving ``TraceAnnotation`` once
+    jax is loaded.  Only a process that imported jax can run the profiler,
     so the numpy route neither imports jax nor records anything; the
     answer is kept once jax is there."""
-    global _annotation
+    global _annotation, _recording
     if "jax" not in sys.modules:
-        return _null
+        return False
     try:
         from jax.profiler import TraceAnnotation
     except ImportError:      # a jax that is loading or broken records nothing
-        return _null
-    _annotation = TraceAnnotation
-    return _annotation
+        return False
+    _annotation, _recording = TraceAnnotation, TraceAnnotation.is_enabled
+    return _recording()
 
 
 def span(name: str, **args):
-    """A host span ``hhzs:<name>`` with the given arguments as its stats."""
-    return (_annotation or _resolve())(PREFIX + name, **args)
+    """A host span ``hhzs:<name>`` with the given arguments as its stats,
+    or the shared null context while the profiler records nothing."""
+    if _recording() if _recording is not None else _resolve():
+        return _annotation(PREFIX + name, **args)
+    return _NULL

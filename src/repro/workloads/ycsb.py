@@ -72,6 +72,20 @@ def mixed(name: str, read_frac: float, alpha: float) -> WorkloadSpec:
                         alpha=alpha)
 
 
+def stamp(key: int, seq: int) -> int:
+    """The 8-byte version a write stores when the store keeps values, as
+    YCSB writes a field value: the key in the high 32 bits and the store's
+    write sequence (``DB.write_seq``, 0 for the load's write) in the low
+    32, so every write of a run stores a stamp of its own."""
+    return (int(key) << 32) | int(seq)
+
+
+def stores_values(db) -> bool:
+    """Whether ``db``'s tree keeps values (``LSMConfig.store_values``)."""
+    sc = getattr(db, "scenario", None)
+    return bool(sc is not None and sc.lsm.store_values)
+
+
 def zipf_probs(n: int, alpha: float) -> np.ndarray:
     ranks = np.arange(1, n + 1, dtype=np.float64)
     p = ranks ** (-alpha)
@@ -162,6 +176,7 @@ class OpStream:
         # multi-tenant runner): rides every put() into the tree, tagging
         # flushed bytes for per-tenant compaction-debt attribution
         self.tenant: Optional[str] = None
+        self._stamps = stores_values(db)
 
     @property
     def tree(self):
@@ -206,6 +221,14 @@ class OpStream:
         self.counts["read"] += len(idxs)
         return res
 
+    def _value(self, key: int):
+        """The value a write of ``key`` stores: a fresh stamp when the
+        store keeps values, else nothing."""
+        if not self._stamps:
+            return None
+        self.db.write_seq = getattr(self.db, "write_seq", 0) + 1
+        return stamp(key, self.db.write_seq)
+
     def execute(self, i: int):
         """Generator running op ``i`` against the tree (virtual-timed)."""
         code = int(self.ops.codes[i])
@@ -216,18 +239,19 @@ class OpStream:
         if code == READ:
             yield from self.tree.get(self.resolve(code, rank, i))
         elif code == UPDATE:
-            yield from self.tree.put(self.resolve(code, rank, i), **kw)
+            key = self.resolve(code, rank, i)
+            yield from self.tree.put(key, self._value(key), **kw)
         elif code == INSERT:
             key = self.frontier
             self.frontier += 1
-            yield from self.tree.put(key, **kw)
+            yield from self.tree.put(key, self._value(key), **kw)
         elif code == SCAN:
             yield from self.tree.scan(self.resolve(code, rank, i),
                                       int(self.ops.scan_lens[i]))
         elif code == RMW:
             key = self.resolve(code, rank, i)
             yield from self.tree.get(key)
-            yield from self.tree.put(key, **kw)
+            yield from self.tree.put(key, self._value(key), **kw)
         self.counts[OP_NAMES[code]] += 1
 
 
@@ -245,6 +269,7 @@ def run_load(db, n_keys: int, num_clients: int = 16, seed: int = 42,
     load_order = rng.permutation(n_keys).astype(np.int64)
     db.load_order = load_order          # recency mapping for workload D
     tree, sim = db.kv, db.sim
+    stamped = stores_values(db)
     t0 = sim.now
     lat: List[float] = []
     cursor = {"i": 0}
@@ -256,7 +281,8 @@ def run_load(db, n_keys: int, num_clients: int = 16, seed: int = 42,
                 return
             cursor["i"] += 1
             s = sim.now
-            yield from tree.put(int(load_order[i]))
+            key = int(load_order[i])
+            yield from tree.put(key, stamp(key, 0) if stamped else None)
             lat.append(sim.now - s)
 
     procs = [sim.process(client()) for _ in range(num_clients)]

@@ -3,6 +3,7 @@ them: the helper without jax, spans that never stay open across a DES
 ``yield``, one span per level and per device call, the bytes handed to the
 device, the kernel's schedule count, and the spans in a real profiler
 trace."""
+import contextlib
 import glob
 import os
 import subprocess
@@ -33,8 +34,14 @@ def test_span_is_one_shared_null_context_without_jax():
                    env={"PYTHONPATH": str(SRC)})
 
 
-def test_span_is_a_trace_annotation_once_jax_is_loaded():
+def test_span_is_a_trace_annotation_once_jax_is_loaded(monkeypatch):
+    """Once jax is loaded a span is a ``TraceAnnotation`` while the
+    profiler records, and the shared null context while it does not."""
     jax = pytest.importorskip("jax")
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    off = spans.span("probe.pad", words=1024)
+    assert off is spans.span("put") and isinstance(off, contextlib.nullcontext)
+    monkeypatch.setattr(spans, "_recording", lambda: True)
     s = spans.span("probe.pad", words=1024)
     assert isinstance(s, jax.profiler.TraceAnnotation)
     with s:
@@ -61,6 +68,7 @@ def recorded(monkeypatch):
     log = []
     monkeypatch.setattr(_Recorded, "log", log)
     monkeypatch.setattr(spans, "_annotation", _Recorded)
+    monkeypatch.setattr(spans, "_recording", lambda: True)
     return log
 
 
@@ -115,6 +123,36 @@ def test_spans_close_in_lifo_order_under_concurrent_reads_and_compaction(
             assert outer[-1] == "probe"
         if name == "probe":
             assert outer[-1] == "get_batch.level"
+
+
+def test_write_path_spans_and_byte_counters(recorded):
+    """The put, the WAL append, the memtable rotation, the placement of a
+    new SST and a migration's moves each open a span, in LIFO order; the
+    middleware counts each write stream's bytes under its device."""
+    from repro.workloads import YCSB, PoissonArrivals, run_open_loop
+    db, n = _jax_db(keys=3000, impl="numpy")
+    be, tree = db.backend, db.tree
+    before, acked0 = dict(be.stats), tree.stats["puts_acked"]
+    recorded.clear()
+    run_open_loop(db, YCSB["A"], PoissonArrivals(60.0), duration=40.0,
+                  n_keys=n, read_batch=8, max_concurrency=16, seed=3)
+    done = _nest(recorded)
+    names = {name for name, _, _ in done}
+    assert {"put", "wal.append", "memtable.rotate", "placement",
+            "migration.move"} <= names
+    for name, _, outer in done:
+        # none wakes another process, so none holds another span
+        if name in ("put", "wal.append", "placement", "migration.move"):
+            assert not outer, (name, outer)
+        if name == "memtable.rotate":
+            assert outer in ((), ("put",))
+    moved = {k: be.stats[k] - before[k] for k in before
+             if k.startswith("write_bytes.")}
+    assert all(v > 0 for v in moved.values()), moved
+    assert (moved["write_bytes.ssd"] + moved["write_bytes.hdd"]
+            == sum(moved["write_bytes." + s]
+                   for s in ("wal", "flush", "compaction", "migration")))
+    assert tree.stats["puts_acked"] > acked0
 
 
 def _expected_levels(tree, batch):
