@@ -155,8 +155,8 @@ WORD_BYTES = 4
 # padded device copies of read-only filter images, least recently used
 # first: id(host image) -> (weak reference to it, device array).  An entry
 # goes when its host image is freed, when its owner releases it
-# (:func:`release`), or when more than RESIDENT_IMAGES are held: a few
-# per level of a default tree
+# (:func:`release`), or when more than RESIDENT_IMAGES are held: the
+# images of a few trees (one each, ``LSMTree._tree_image``)
 _resident: "OrderedDict[int, tuple]" = OrderedDict()
 RESIDENT_IMAGES = 14
 # what the device route has handed the device since the process started:

@@ -129,15 +129,21 @@ class LSMTree:
             "puts": 0, "puts_acked": 0, "gets": 0, "hits": 0, "scans": 0,
             "write_stalls": 0, "compactions": 0, "flushes": 0,
             "bloom_fp": 0, "filter_probes": 0, "delayed_writes": 0,
-            # device probe calls, the level images uploaded to stay on the
+            # device probe calls, the tree images uploaded to stay on the
             # device, and the bytes handed the device, padding included:
             # each call's pairs, and each image when it is uploaded
             "probe_calls": 0, "probe_image_uploads": 0, "probe_h2d_bytes": 0,
+            # (key, SST) pairs handed to get_batch's Bloom probe, on every
+            # route, and the probes a membership change made it repeat
+            "probe_pairs": 0, "probe_reprobes": 0,
         }
-        # per-level read index (sorted candidate arrays + concatenated
-        # filter image), dropped whenever the level's membership changes
-        # and rebuilt lazily — see _level_index
+        # per-level read index (sorted candidate arrays), dropped whenever
+        # the level's membership changes and rebuilt lazily — see
+        # _level_index; the filter image of the whole tree, dropped on any
+        # membership change (_tree_image); and the count of those changes
         self._ridx: Dict[int, Tuple] = {}
+        self._image: Optional[Tuple[np.ndarray, Dict]] = None
+        self._epoch = 0
 
     # ------------------------------------------------------------------
     def _on_evict(self, sst_id: int, block_idx: int) -> None:
@@ -167,11 +173,13 @@ class LSMTree:
         self._membership_changed(sst.level)
 
     def _membership_changed(self, level: int) -> None:
-        """Drop the level's read index, and free the device copy of its
-        filter image: the image is probed no more."""
-        idx = self._ridx.pop(level, None)
-        if idx is not None and idx[4] is not None:
-            filters.release(idx[4])
+        """Drop the level's read index and the tree's filter image, and
+        free the image's device copy: it is probed no more."""
+        self._epoch += 1
+        self._ridx.pop(level, None)
+        if self._image is not None:
+            filters.release(self._image[0])
+            self._image = None
 
     def compaction_debt(self) -> int:
         return sum(max(0, self._level_bytes[l] - self.cfg.target_of(l))
@@ -618,9 +626,8 @@ class LSMTree:
     def _level_index(self, lvl: int):
         """Read index for one level, rebuilt only after the level's
         membership changes (SST install/remove): candidate SSTs in
-        lookup order, their key ranges as plain ints / a sorted uint64
-        array for bisection, and the level's concatenated filter image
-        for the vectorized batch probe.
+        lookup order, and their key ranges as plain ints / a sorted uint64
+        array for bisection.
 
         L0 files overlap, so they are ordered newest-first by ``birth`` —
         the list's install order is NOT trustworthy (after ``DB.reopen()``
@@ -641,16 +648,25 @@ class LSMTree:
                 mins = [s.min_key for s in ssts]
                 mins_np = np.array(mins, dtype=np.uint64)
             maxs = [s.max_key for s in ssts]
-            bits, offsets = (filters.concat_filters(ssts)
-                             if self.cfg.filters == "real" else (None, None))
-        idx = (ssts, mins, mins_np, maxs, bits, offsets)
+        idx = (ssts, mins, mins_np, maxs)
         self._ridx[lvl] = idx
         return idx
+
+    def _tree_image(self) -> Tuple[np.ndarray, Dict]:
+        """The concatenated filter words of every installed SST, with each
+        SST's (word offset, word count) by ``sid`` (unique across levels),
+        rebuilt only after a membership change.  The image is frozen, so
+        the device route keeps it resident between changes."""
+        if self._image is None:
+            with span("tree_image"):
+                self._image = filters.concat_filters(
+                    [s for ssts in self.levels for s in ssts])
+        return self._image
 
     def _level_candidates(self, lvl: int, key: int) -> List[SST]:
         """SSTs of level ``lvl`` whose range covers ``key``, in lookup
         order (see _level_index for the ordering contract)."""
-        ssts, mins, _, maxs, _, _ = self._level_index(lvl)
+        ssts, mins, _, maxs = self._level_index(lvl)
         if lvl == 0:
             return [s for s in ssts if s.min_key <= key <= s.max_key]
         j = bisect_right(mins, key) - 1
@@ -714,11 +730,18 @@ class LSMTree:
         Result-identical to per-key :meth:`get` (asserted across every
         scheme by ``tests/test_differential.py``): the same newest-first
         lookup order, the same block I/O per surviving candidate.  The
-        difference is *how* candidates are found and probed — per level,
-        the (key x candidate-SST) pairs of all still-unresolved keys are
-        filtered in one vectorized Bloom call (numpy fallback or the
-        ``bloom_probe`` kernel family, per ``LSMConfig.filter_impl``), and
-        only survivors reach the block cache / backend."""
+        difference is *how* candidates are found and probed — the (key x
+        candidate-SST) pairs of every level for all keys the memtables did
+        not answer are filtered in one vectorized Bloom call (numpy
+        fallback or the ``bloom_probe`` kernel family, per
+        ``LSMConfig.filter_impl``), and the survivors are then walked level
+        by level; only they reach the block cache / backend.
+
+        The walk yields, and a flush or compaction may change the levels
+        meanwhile.  Before each level's walk, a membership change since the
+        probe gathers and probes the remaining levels again for the keys
+        still pending, so every level's candidates are those of its
+        membership when its walk starts, as in a per-level probe."""
         n = len(keys)
         self.stats["gets"] += n
         results: List[Optional[Tuple[bool, Optional[bytes]]]] = [None] * n
@@ -729,33 +752,38 @@ class LSMTree:
                 results[i] = mem
             else:
                 pending.append(i)
-        real = self.cfg.filters == "real"
+        probed: Dict[int, Dict[int, Tuple[int, List[SST]]]] = {}
+        hits = None
+        epoch = None
         for lvl in range(len(self.levels)):
             if not pending:
                 break
             if not self.levels[lvl]:
                 continue
-            # the level's candidates and their probe; the span closes
-            # before the survivors' walk, which yields
-            with span("get_batch.level", level=lvl):
-                pair_of, flat, hits = self._probe_level(lvl, keys, pending,
-                                                        real)
-            if not flat:
+            if epoch != self._epoch:
+                if epoch is not None:
+                    self.stats["probe_reprobes"] += 1
+                epoch = self._epoch
+                # the candidates and their probe; the span closes before
+                # the survivors' walk, which yields
+                with span("get_batch.levels", first=lvl):
+                    probed, hits = self._probe_levels(lvl, keys, pending)
+            level = probed.get(lvl)
+            if not level:
                 continue
             # walk survivors per key in candidate order, stopping at the
             # first exact hit — byte-identical I/O to the per-key path
-            self.stats["filter_probes"] += len(flat)
-            cursor = 0
             still: List[int] = []
-            for i, cands in zip(pending, pair_of):
+            for i in pending:
+                start, cands = level.get(i, (0, ()))
+                self.stats["filter_probes"] += len(cands)
                 key = keys[i]
                 for j, sst in enumerate(cands):
-                    if results[i] is not None or not hits[cursor + j]:
+                    if results[i] is not None or not hits[start + j]:
                         continue
                     res = yield from self._probe_sst(sst, key)
                     if res is not None:
                         results[i] = res
-                cursor += len(cands)
                 if results[i] is None:
                     still.append(i)
             pending = still
@@ -763,49 +791,61 @@ class LSMTree:
             results[i] = (False, None)
         return results
 
-    def _probe_level(self, lvl: int, keys: List[int], pending: List[int],
-                     real: bool):
-        """One level of :meth:`get_batch`: the candidate SSTs of each
-        pending key, grouped per key in lookup order, the flat (key index,
-        SST) pairs, and the Bloom answer for each pair."""
-        ssts, _, mins_np, maxs, bits, offsets = self._level_index(lvl)
-        # deeper levels are disjoint, so one searchsorted over the whole
-        # batch replaces per-key range scans
-        pair_of: List[List[SST]] = []
-        if lvl == 0:
-            for i in pending:
-                k = keys[i]
-                pair_of.append([s for s in ssts
-                                if s.min_key <= k <= s.max_key])
-        else:
-            karr = np.fromiter((keys[i] for i in pending),
-                               np.uint64, len(pending))
-            pos = np.searchsorted(mins_np, karr, side="right") - 1
-            for t, i in enumerate(pending):
-                j = int(pos[t])
-                pair_of.append([ssts[j]] if j >= 0
-                               and keys[i] <= maxs[j] else [])
-        flat = [(i, sst) for i, cands in zip(pending, pair_of)
-                for sst in cands]
-        if not flat:
-            return pair_of, flat, None
-        if real:
+    def _probe_levels(self, first: int, keys: List[int],
+                      pending: List[int]):
+        """The candidate SSTs of each pending key in every level from
+        ``first`` down, and the Bloom answer of every (key, SST) pair in
+        one probe.  Returns ({level: {key index: (first pair, candidates
+        in lookup order)}}, hits), keys without a candidate in a level
+        left out of its map."""
+        probed: Dict[int, Dict[int, Tuple[int, List[SST]]]] = {}
+        pair_keys: List[int] = []
+        pair_ssts: List[SST] = []
+        karr = None
+        for lvl in range(first, len(self.levels)):
+            if not self.levels[lvl]:
+                continue
+            ssts, _, mins_np, maxs = self._level_index(lvl)
+            found: Dict[int, Tuple[int, List[SST]]] = {}
+            if lvl == 0:
+                for i in pending:
+                    k = keys[i]
+                    cands = [s for s in ssts if s.min_key <= k <= s.max_key]
+                    if cands:
+                        found[i] = (len(pair_ssts), cands)
+                        pair_keys.extend([k] * len(cands))
+                        pair_ssts.extend(cands)
+            else:
+                # deeper levels are disjoint, so one searchsorted over the
+                # whole batch replaces per-key range scans
+                if karr is None:
+                    karr = np.fromiter((keys[i] for i in pending),
+                                       np.uint64, len(pending))
+                pos = np.searchsorted(mins_np, karr, side="right") - 1
+                for t, i in enumerate(pending):
+                    j = int(pos[t])
+                    if j >= 0 and keys[i] <= maxs[j]:
+                        found[i] = (len(pair_ssts), [ssts[j]])
+                        pair_keys.append(keys[i])
+                        pair_ssts.append(ssts[j])
+            if found:
+                probed[lvl] = found
+        if not pair_ssts:
+            return probed, None
+        self.stats["probe_pairs"] += len(pair_ssts)
+        if self.cfg.filters == "real":
             hits = self._probe_pairs_real(
-                np.array([keys[i] for i, _ in flat], dtype=np.uint64),
-                [sst for _, sst in flat], bits, offsets)
+                np.array(pair_keys, dtype=np.uint64), pair_ssts)
         else:
-            hits = [sst.bloom_maybe_contains(keys[i], self.cfg.bloom_fp_rate)
-                    for i, sst in flat]
-        return pair_of, flat, hits
+            hits = [sst.bloom_maybe_contains(k, self.cfg.bloom_fp_rate)
+                    for k, sst in zip(pair_keys, pair_ssts)]
+        return probed, hits
 
     def _probe_pairs_real(self, pair_keys: np.ndarray,
-                          pair_ssts: List[SST],
-                          bits: Optional[np.ndarray] = None,
-                          offsets: Optional[Dict] = None) -> np.ndarray:
-        """Vectorized real-filter probe over (key, SST) pairs, against a
-        precomputed filter image (``_level_index``) when available."""
-        if bits is None:
-            bits, offsets = filters.concat_filters(pair_ssts)
+                          pair_ssts: List[SST]) -> np.ndarray:
+        """Vectorized real-filter probe over (key, SST) pairs against the
+        tree's filter image (``_tree_image``)."""
+        bits, offsets = self._tree_image()
         # filterless SSTs (built under another mode) always pass
         hits = np.ones(len(pair_ssts), dtype=bool)
         mask = np.array([s.sid in offsets for s in pair_ssts], dtype=bool)

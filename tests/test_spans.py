@@ -1,6 +1,7 @@
 """Host spans of the store (``repro.obs.spans``) and the counters beside
 them: the helper without jax, spans that never stay open across a DES
-``yield``, one span per level and per device call, the bytes handed to the
+``yield``, one device call per read batch against one tree-wide filter
+image, the re-probe after a membership change, the bytes handed to the
 device, the kernel's schedule count, and the spans in a real profiler
 trace."""
 import contextlib
@@ -89,12 +90,15 @@ def _nest(log):
 
 
 def _jax_db(keys=1200, impl="jax"):
+    """A loaded store whose background jobs have all finished, so its
+    levels hold still until the next write."""
     from repro.workloads import run_load
     sc = tiny_scenario()
     sc = replace(sc, lsm=replace(sc.lsm, filter_impl=impl))
     db = DB("HHZS", sc, store_values=True)
     run_load(db, n_keys=keys)
     db.flush_all()
+    db.drain()
     return db, keys
 
 
@@ -111,7 +115,8 @@ def test_spans_close_in_lifo_order_under_concurrent_reads_and_compaction(
     assert res.n_measured > 300
     assert db.tree.stats["compactions"] > before["compactions"]
     names = {name for name, _, _ in done}
-    assert {"get_batch.level", "level_index", "block_lookup", "probe",
+    assert {"get_batch.levels", "level_index", "tree_image",
+            "block_lookup", "probe",
             "probe.upload", "probe.pad", "probe.call", "probe.read", "hint",
             "migration.pick", "compaction.merge", "flush.merge",
             "sst.build", "filter.build"} <= names
@@ -121,8 +126,8 @@ def test_spans_close_in_lifo_order_under_concurrent_reads_and_compaction(
         if name in ("probe.upload", "probe.pad", "probe.call",
                     "probe.read"):
             assert outer[-1] == "probe"
-        if name == "probe":
-            assert outer[-1] == "get_batch.level"
+        if name in ("probe", "tree_image"):
+            assert outer[-1] == "get_batch.levels"
 
 
 def test_write_path_spans_and_byte_counters(recorded):
@@ -155,49 +160,77 @@ def test_write_path_spans_and_byte_counters(recorded):
     assert tree.stats["puts_acked"] > acked0
 
 
-def _expected_levels(tree, batch):
-    """Levels ``get_batch`` visits for a batch of loaded keys, each held
-    once, and the levels among them where some pending key has a
-    candidate SST (one device call each)."""
+def _expected_pairs(tree, batch):
+    """For a batch of loaded keys, each held once: the first level that
+    holds an SST, the (key, SST) pairs of every level (what the one probe
+    gets), and the pairs of the levels the walk reaches for each key, down
+    to the level that holds it."""
     where = {}
     for lvl, ssts in enumerate(tree.levels):
         for s in ssts:
             for k in batch:
                 if s.min_key <= k <= s.max_key and s.find(k)[0]:
                     where.setdefault(k, lvl)
-    deepest = max(where[k] for k in batch)
-    visited = [l for l in range(deepest + 1) if tree.levels[l]]
-    called = [l for l in visited
-              if any(s.min_key <= k <= s.max_key for s in tree.levels[l]
-                     for k in batch if where[k] >= l)]
-    return visited, called
+    first = min(l for l, ssts in enumerate(tree.levels) if ssts)
+    covers = [(k, l) for k in batch for l, ssts in enumerate(tree.levels)
+              for s in ssts if s.min_key <= k <= s.max_key]
+    reached = [(k, l) for k, l in covers if l <= where[k]]
+    return first, len(covers), len(reached)
 
 
+@pytest.mark.parametrize("size", [1, 3, 8, 64])
 def test_one_level_span_per_probed_level_and_one_of_each_per_call(
-        recorded):
+        recorded, size):
+    """While the levels hold still, a read batch makes one device call
+    against the tree's image, under one ``get_batch.levels`` span, and
+    hands it the pairs of every level; ``filter_probes`` counts only the
+    pairs of the levels the walk reached."""
     pytest.importorskip("jax")
     db, n = _jax_db()
-    rng = np.random.default_rng(4)
-    for size in (1, 3, 8):
+    stats = db.tree.stats
+    rng = np.random.default_rng(4 + size)
+    for _ in range(2):
         batch = [int(k) for k in rng.choice(n, size, replace=False)]
-        visited, called = _expected_levels(db.tree, batch)
-        calls0 = db.tree.stats["probe_calls"]
-        uploads0 = db.tree.stats["probe_image_uploads"]
+        first, pairs, reached = _expected_pairs(db.tree, batch)
+        before = dict(stats)
         recorded.clear()
         got = db.get_batch(batch)
         assert all(found for found, _ in got)
         done = _nest(recorded)
-        levels = [a["level"] for name, a, _ in done
-                  if name == "get_batch.level"]
-        assert levels == visited
-        assert db.tree.stats["probe_calls"] - calls0 == len(called)
+        assert [a["first"] for name, a, _ in done
+                if name == "get_batch.levels"] == [first]
+        assert stats["probe_calls"] - before["probe_calls"] == 1
+        assert stats["probe_reprobes"] == before["probe_reprobes"]
+        assert stats["probe_pairs"] - before["probe_pairs"] == pairs
+        assert stats["filter_probes"] - before["filter_probes"] == reached
         for name in ("probe", "probe.pad", "probe.call", "probe.read"):
-            assert sum(1 for m, _, _ in done if m == name) == len(called)
+            assert sum(1 for m, _, _ in done if m == name) == 1
         uploads = [a["words"] for m, a, _ in done if m == "probe.upload"]
-        assert (db.tree.stats["probe_image_uploads"] - uploads0
-                == len(uploads) <= len(called))
+        assert (stats["probe_image_uploads"] - before["probe_image_uploads"]
+                == len(uploads) <= 1)
         pads = [a["words"] for m, a, _ in done if m == "probe.pad"]
         assert all(w >= 1024 and w & (w - 1) == 0 for w in pads + uploads)
+
+
+@pytest.mark.parametrize("impl", ["numpy", "jax"])
+def test_filter_probes_count_the_pairs_of_the_levels_the_walk_reached(
+        impl):
+    """Every route hands the probe the pairs of all levels at once, and
+    counts as ``filter_probes`` the pairs of the levels each key's walk
+    reached, as the per-level probe did."""
+    if impl == "jax":
+        pytest.importorskip("jax")
+    db, n = _jax_db(impl=impl)
+    stats = db.tree.stats
+    batch = list(range(0, n, 5))
+    _, pairs, reached = _expected_pairs(db.tree, batch)
+    assert reached < pairs
+    before = dict(stats)
+    db.get_batch(batch)
+    assert stats["probe_pairs"] - before["probe_pairs"] == pairs
+    assert stats["filter_probes"] - before["filter_probes"] == reached
+    assert stats["probe_calls"] - before["probe_calls"] == (
+        impl == "jax")
 
 
 def test_probe_h2d_bytes_are_the_padded_arrays_the_device_got(monkeypatch):
@@ -232,24 +265,25 @@ def test_probe_h2d_bytes_are_the_padded_arrays_the_device_got(monkeypatch):
 
 
 def test_each_level_image_is_uploaded_once_while_its_membership_holds():
+    """The tree's one image is uploaded on the first batch and serves every
+    later batch while no SST is installed or removed."""
     pytest.importorskip("jax")
+    from repro.lsm import filters
     db, n = _jax_db()
     stats = db.tree.stats
     rng = np.random.default_rng(8)
-    probed = set()
     for _ in range(5):
-        batch = [int(k) for k in rng.choice(n, 8, replace=False)]
-        probed.update(_expected_levels(db.tree, batch)[1])
-        db.get_batch(batch)
-    assert stats["probe_calls"] > len(probed)
-    assert stats["probe_image_uploads"] == len(probed)
+        db.get_batch([int(k) for k in rng.choice(n, 8, replace=False)])
+    assert stats["probe_calls"] == 5
+    assert stats["probe_image_uploads"] == 1
+    image = db.tree._image[0]
+    assert filters._resident[id(image)][0]() is image
 
 
 def test_a_compaction_uploads_the_level_images_it_changed():
-    """After compactions change levels' membership, the next probe of each
-    changed level uploads its new image and frees the old one's device
-    copy; every answer matches the numpy route's, so no stale image
-    answers."""
+    """After compactions change the levels' membership, the old tree image
+    leaves the device, and the next batch uploads the new one once; every
+    answer matches the numpy route's, so no stale image answers."""
     pytest.importorskip("jax")
     from repro.lsm import filters
     dbs = [_jax_db(impl=impl)[0] for impl in ("jax", "numpy")]
@@ -257,31 +291,103 @@ def test_a_compaction_uploads_the_level_images_it_changed():
     batch = list(range(0, 1200, 25))
     first = [db.get_batch(batch) for db in dbs]
     assert first[0] == first[1]
-    old = {lvl: idx[4] for lvl, idx in tree._ridx.items()
-           if idx[4] is not None and len(idx[4])}
-    assert all(id(img) in filters._resident for img in old.values())
-    members = [list(ssts) for ssts in tree.levels]
+    old = tree._image[0]
+    assert id(old) in filters._resident
     for db in dbs:
         rng = np.random.default_rng(10)
         for i, k in enumerate(rng.integers(0, 1500, size=900)):
             db.put(int(k), b"w%d" % i)
         db.drain()
-    assert dbs[0].tree.stats["compactions"] > 0
-    moved = [l for l in old if tree.levels[l] != members[l]]
-    assert moved
-    # a changed level's old image leaves the device when the level changes
-    assert all(id(old[l]) not in filters._resident for l in moved)
+    assert tree.stats["compactions"] > 0
+    # the old image leaves the device when the membership changes
+    assert tree._image is None and id(old) not in filters._resident
     uploads0 = tree.stats["probe_image_uploads"]
+    calls0 = tree.stats["probe_calls"]
     keys = list(range(0, 1600, 3))
     answers = [db.get_batch(keys) for db in dbs]
     assert answers[0] == answers[1]
-    assert tree.stats["probe_image_uploads"] - uploads0 >= len(
-        [l for l in moved if tree.levels[l]])
-    for lvl in moved:
-        if tree.levels[lvl]:
-            new = tree._ridx[lvl][4]
-            assert new is not old[lvl]
-            assert filters._resident[id(new)][0]() is new
+    assert tree.stats["probe_calls"] - calls0 == 1
+    assert tree.stats["probe_image_uploads"] - uploads0 == 1
+    new = tree._image[0]
+    assert new is not old and filters._resident[id(new)][0]() is new
+    assert len(new) == sum(len(s.filter_words) for ssts in tree.levels
+                           for s in ssts)
+
+
+def _change_during_walk(db, how):
+    """Make ``db``'s tree change its membership while ``get_batch`` yields
+    in its first survivor's block lookup: a flush of fresh keys into L0,
+    or a compaction-style rewrite of the deepest level's SSTs (same keys
+    and versions, new SSTs).  Returns the rewritten SSTs, old and new."""
+    tree = db.tree
+    real = tree._probe_sst
+    rewritten = []
+
+    def change():
+        if how == "flush":
+            for k in range(5000, 5100):
+                yield from tree.put(k, b"fresh%d" % k)
+            yield from tree.flush_all()
+            return
+        deepest = max(l for l, ssts in enumerate(tree.levels) if ssts)
+        for old in list(tree.levels[deepest]):
+            new = tree._make_sst(old.keys, old.tombs, level=deepest,
+                                 values=old.values)
+            yield from tree.backend.write_sst(new, source="compaction")
+            tree._remove_sst(old)
+            tree.block_cache.drop_sst(old.sid)
+            tree._install_sst(new, deepest)
+            tree.backend.delete_sst(old)
+            rewritten.append((old, new))
+        tree.levels[deepest].sort(key=lambda s: s.min_key)
+
+    def probe_sst(sst, key):
+        if tree._probe_sst is probe_sst:
+            tree._probe_sst = real
+            yield from change()
+        return (yield from real(sst, key))
+
+    tree._probe_sst = probe_sst
+    return rewritten
+
+
+@pytest.mark.parametrize("how", ["flush", "rewrite"])
+def test_a_membership_change_during_the_walk_probes_the_rest_again(how):
+    """A flush or compaction that installs SSTs while the walk yields makes
+    the next level's walk probe the remaining levels again, so each level
+    walks the SSTs it holds when its walk starts; answers equal the numpy
+    route's and per-key ``get``'s."""
+    pytest.importorskip("jax")
+    dbs = [_jax_db(impl=impl)[0] for impl in ("jax", "numpy")]
+    levels = dbs[0].tree.levels
+    shallow = min(l for l, ssts in enumerate(levels) if ssts)
+    deep = max(l for l, ssts in enumerate(levels) if ssts)
+    assert shallow < deep
+    # a key the shallowest level holds comes first, so the walk yields
+    # there with keys of the deepest level still pending
+    batch = ([int(levels[shallow][0].keys[0])]
+             + [int(s.keys[len(s.keys) // 2]) for s in levels[deep]])
+    answers = []
+    for db in dbs:
+        stats = db.tree.stats
+        before = dict(stats)
+        rewritten = _change_during_walk(db, how)
+        answers.append(db.get_batch(batch))
+        reprobes = stats["probe_reprobes"] - before["probe_reprobes"]
+        assert reprobes >= 1
+        if db is dbs[0]:
+            assert stats["probe_calls"] - before["probe_calls"] == (
+                1 + reprobes)
+        if how == "rewrite":
+            # the deepest level's walk read the new SSTs, never the old
+            assert rewritten
+            assert all(old.num_reads == 0 for old, _ in rewritten)
+            assert sum(new.num_reads for _, new in rewritten) >= len(
+                levels[deep])
+        db.drain()
+        assert answers[-1] == [db.get(k) for k in batch]
+    assert answers[0] == answers[1]
+    assert all(found for found, _ in answers[0])
 
 
 def test_numpy_route_counts_no_device_calls():
@@ -349,6 +455,6 @@ def test_spans_reach_the_profiler_trace_inside_an_outer_annotation(tmp_path):
     (_, lo, hi), = [h for h in host if h[0] == "outer"]
     ours = [h for h in host if h[0] != "outer"]
     names = {h[0] for h in ours}
-    assert {"hhzs:get_batch.level", "hhzs:probe", "hhzs:probe.pad",
+    assert {"hhzs:get_batch.levels", "hhzs:probe", "hhzs:probe.pad",
             "hhzs:probe.call", "hhzs:probe.read"} <= names
     assert all(lo <= a <= b <= hi for _, a, b in ours)
