@@ -15,12 +15,12 @@ arrive and depart mid-run.  This module adds the missing axis:
   an analytical phase), and the live tenant set (departing tenants drain
   in-flight ops against a deadline; arriving tenants get fresh seeded
   ``OpStream``\\ s).
-* :func:`run_drift` executes a program against one store with the same
-  bounded server pool / queueing-vs-service decomposition as
-  ``run_open_loop``, and reports **per-phase metric windows**: each
-  per-tenant row carries a ``phases`` column with per-phase throughput and
-  queueing/service p99 (an op straddling a boundary is counted in exactly
-  one window — the phase it *arrived* in).
+* :func:`run_drift` executes a program against one store on the
+  open-loop engine that ``run_open_loop`` runs on
+  (``repro.workloads.runner._serve``), and reports **per-phase metric
+  windows**: each per-tenant row carries a ``phases`` column with
+  per-phase throughput and queueing/service p99 (an op straddling a
+  boundary is counted in exactly one window — the phase it *arrived* in).
 * :func:`phase_rankings` / :func:`rank_flips` compare schemes' per-phase
   throughput across rows of a sweep and count the phase transitions where
   the scheme ordering changes — the run-level ``rank_flips`` summary the
@@ -35,15 +35,14 @@ grid-smoke drift leg).
 """
 from __future__ import annotations
 
-from collections import deque
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
 from .runner import (ArrivalProcess, BurstyArrivals, OpenLoopResult,
-                     PoissonArrivals)
-from .ycsb import (READ, SCAN, OpStream, Ops, WorkloadSpec, YCSB, _pct,
+                     PoissonArrivals, _result, _serve, _Source)
+from .ycsb import (SCAN, OpStream, Ops, WorkloadSpec, YCSB, _pct,
                    collect_extras)
 
 
@@ -134,18 +133,8 @@ def inject_scan_burst(ops: Ops, frac: float, scan_len: int,
 
 
 # ======================================================================
-# the engine
+# the drift front end
 # ======================================================================
-@dataclass
-class _Slice:
-    """One (tenant x live phase) pre-generated arrival/op slice."""
-
-    ti: int
-    k: int
-    rel: np.ndarray                       # absolute-relative arrival times
-    stream: OpStream
-
-
 def run_drift(db, program: TraceProgram, *, n_keys: int = 0,
               warmup: float = 0.0, max_concurrency: int = 64,
               seed: int = 1) -> List[OpenLoopResult]:
@@ -155,9 +144,11 @@ def run_drift(db, program: TraceProgram, *, n_keys: int = 0,
     Every (tenant x live-phase) pair gets its own arrival-time array and
     fresh seeded ``OpStream`` (seeds stride by tenant *and* phase index),
     generated before the first event fires — the op sequence is a pure
-    function of ``(program, n_keys, seed)``.  The merged arrival stream
-    feeds one bounded pool of ``max_concurrency`` servers, exactly like
-    ``run_multi_tenant`` without admission control.
+    function of ``(program, n_keys, seed)``.  Each pair is one source of
+    the open-loop engine (``repro.workloads.runner._serve``), with no
+    admission step: the merged arrival stream feeds one bounded pool of
+    ``max_concurrency`` servers, exactly like ``run_multi_tenant`` under
+    policy ``none``.
 
     Phase-window accounting assigns each op to the phase it *arrived* in
     (a boundary straddler counts in exactly one window); per tenant,
@@ -184,8 +175,8 @@ def run_drift(db, program: TraceProgram, *, n_keys: int = 0,
     bounds = program.bounds()
     total = bounds[-1][1]
 
-    # ---- pre-generate every (tenant x live phase) slice -------------
-    slices: List[_Slice] = []
+    # ---- pre-generate every (tenant x live phase) source ------------
+    sources: List[_Source] = []
     for ti, ten in enumerate(tenants):
         for k, ph in enumerate(phases):
             if not program.live_in(ph, ten.name):
@@ -204,30 +195,7 @@ def run_drift(db, program: TraceProgram, *, n_keys: int = 0,
             inject_scan_burst(
                 stream.ops, ph.scan_burst, ph.scan_len,
                 np.random.default_rng(seed + 5 + 9973 * ti + 101 * k))
-            slices.append(_Slice(ti=ti, k=k, rel=rel, stream=stream))
-
-    m_at = (np.concatenate([s.rel for s in slices])
-            if slices else np.empty(0, np.float64))
-    m_si = np.concatenate([np.full(len(s.rel), si, np.int64)
-                           for si, s in enumerate(slices)]) \
-        if slices else np.empty(0, np.int64)
-    m_i = np.concatenate([np.arange(len(s.rel), dtype=np.int64)
-                          for s in slices]) \
-        if slices else np.empty(0, np.int64)
-    order = np.argsort(m_at, kind="stable")   # ties: tenant/phase order
-    m_at, m_si, m_i = m_at[order], m_si[order], m_i[order]
-    m = len(m_at)
-
-    t0 = sim.now
-    arrive = [np.full(len(s.rel), np.nan) for s in slices]
-    start = [np.full(len(s.rel), np.nan) for s in slices]
-    done = [np.full(len(s.rel), np.nan) for s in slices]
-    dropped = [np.zeros(len(s.rel), bool) for s in slices]
-    queue: deque = deque()
-    idle: List = []                       # events of parked servers
-    depth = [0] * len(tenants)            # per-tenant ops in queue
-    tmax_depth = [0] * len(tenants)
-    state = {"closed": False, "max_depth": 0, "next": 0}
+            sources.append(_Source(ti, rel, stream, phase=k))
 
     # departure boundaries: tenants live in phase k-1 but not in phase k
     departures: List[Tuple[float, int, frozenset]] = []
@@ -242,6 +210,7 @@ def run_drift(db, program: TraceProgram, *, n_keys: int = 0,
 
     # phase-boundary markers on the telemetry bus (pull-only: marks are
     # recorded via daemon timeouts and never perturb the event schedule)
+    t0 = sim.now
     reg = getattr(db, "metrics", None)
     if reg is not None and hasattr(reg, "mark"):
         def marker():
@@ -252,100 +221,37 @@ def run_drift(db, program: TraceProgram, *, n_keys: int = 0,
                 reg.mark(f"phase:{phases[k].name}")
         sim.process(marker())
 
-    def dispatcher():
-        while state["next"] < m:
-            j = state["next"]
-            at = t0 + float(m_at[j])
-            if at > sim.now:
-                yield at - sim.now   # bare-delay: no Event
-            si, i = int(m_si[j]), int(m_i[j])
-            arrive[si][i] = sim.now
-            state["next"] = j + 1
-            ti = slices[si].ti
-            queue.append((si, i))
-            depth[ti] += 1
-            if depth[ti] > tmax_depth[ti]:
-                tmax_depth[ti] = depth[ti]
-            if len(queue) > state["max_depth"]:
-                state["max_depth"] = len(queue)
-            if idle:
-                idle.pop().succeed()
-        state["closed"] = True
-        while idle:
-            idle.pop().succeed()
-
-    def server():
-        while True:
-            while not queue:
-                if state["closed"]:
-                    return
-                ev = sim.event()
-                idle.append(ev)
-                yield ev
-            si, i = queue.popleft()
-            depth[slices[si].ti] -= 1
-            start[si][i] = sim.now
-            yield from slices[si].stream.execute(i)
-            done[si][i] = sim.now
-
-    def reaper(at_rel: float, k: int, gone: frozenset):
-        # departure boundary: cancel the departed tenants' queued (not
-        # yet started) ops; in-service ops drain toward the deadline
-        at = t0 + at_rel
-        if at > sim.now:
-            yield at - sim.now   # bare-delay: no Event
-        kept = deque()
-        while queue:
-            si, i = queue.popleft()
-            if names[slices[si].ti] in gone and slices[si].k < k:
-                dropped[si][i] = True
-                depth[slices[si].ti] -= 1
-            else:
-                kept.append((si, i))
-        queue.extend(kept)
-
-    procs = [db.submit(server()) for _ in range(max_concurrency)]
-    procs.append(db.submit(dispatcher()))
-    for at_rel, k, gone in departures:
-        procs.append(sim.process(reaper(at_rel, k, gone)))
-    for p in procs:
-        sim.run_until(p)
-    busy_span = max(sim.now - t0, 1e-12)
+    # at each departure the engine drops the queued ops of the departed
+    # tenants' earlier phases
+    run = _serve(db, sources, len(tenants), duration=total, drain=True,
+                 max_concurrency=max_concurrency,
+                 departures=[(at_rel, frozenset(
+                     si for si, s in enumerate(sources)
+                     if names[s.group] in gone and s.phase < k))
+                     for at_rel, k, gone in departures])
 
     # ---- per-tenant, per-phase accounting ---------------------------
     extras = collect_extras(db)
     results: List[OpenLoopResult] = []
     for ti, ten in enumerate(tenants):
-        mine = [si for si, s in enumerate(slices) if s.ti == ti]
-        arr = np.concatenate([arrive[si] for si in mine])
-        st = np.concatenate([start[si] for si in mine])
-        dn = np.concatenate([done[si] for si in mine])
-        drp = np.concatenate([dropped[si] for si in mine])
-        completed = ~np.isnan(dn)
-        measured = completed & (arr - t0 >= warmup)
-        lat = dn - arr
-        qdel = st - arr
-        serv = dn - st
-        codes = np.concatenate([slices[si].stream.ops.codes for si in mine]) \
-            if mine else np.empty(0, np.int8)
-        reads = (codes == READ) & measured
-
+        mine = [si for si, s in enumerate(sources) if s.group == ti]
         phase_rows: List[Dict] = []
         for si in mine:
-            s = slices[si]
-            b0, b1 = bounds[s.k]
-            c = ~np.isnan(done[si])
-            mz = c & (arrive[si] - t0 >= warmup)
-            tt = done[si] - arrive[si]
-            qq = start[si] - arrive[si]
-            vv = done[si] - start[si]
+            s = sources[si]
+            b0, b1 = bounds[s.phase]
+            arr, dn = run.arrive[si], run.done[si]
+            c = ~np.isnan(dn)
+            mz = c & (arr - t0 >= warmup)
+            tt = dn - arr
+            qq = run.start[si] - arr
+            vv = dn - run.start[si]
             phase_rows.append({
-                "phase": s.k, "name": phases[s.k].name,
+                "phase": s.phase, "name": phases[s.phase].name,
                 "t0": b0, "t1": b1,
                 "workload": s.stream.spec.name,
-                "n_arrived": int(len(arrive[si])),
+                "n_arrived": int(len(arr)),
                 "n_completed": int(c.sum()),
-                "n_dropped": int(dropped[si].sum()),
+                "n_dropped": int(run.dropped[si].sum()),
                 "n_measured": int(mz.sum()),
                 "throughput": float(c.sum()) / max(b1 - b0, 1e-12),
                 "latency_p99": _pct(tt[mz])["p99"],
@@ -359,30 +265,16 @@ def run_drift(db, program: TraceProgram, *, n_keys: int = 0,
                 continue
             deadline = t0 + at_rel + program.drain_s
             for si in mine:
-                if slices[si].k < k:
-                    d = done[si]
+                if sources[si].phase < k:
+                    d = run.done[si]
                     violations += int((d[~np.isnan(d)] > deadline).sum())
 
-        counts: Dict[str, int] = {}
-        for si in mine:
-            for op, c in slices[si].stream.counts.items():
-                counts[op] = counts.get(op, 0) + c
-        results.append(OpenLoopResult(
-            name=program.name, scheme=db.scheme, arrival=ten.arrival.name,
-            n_arrived=int(len(arr)), n_measured=int(measured.sum()),
-            duration=total,
-            offered_rate=len(arr) / max(total, 1e-12),
-            throughput=float(completed.sum()) / busy_span,
-            latency_p=_pct(lat[measured]), queue_p=_pct(qdel[measured]),
-            service_p=_pct(serv[measured]),
-            read_latency_p=_pct(lat[reads]),
-            mean_latency=float(lat[measured].mean()) if measured.any() else 0.0,
-            mean_queue=float(qdel[measured].mean()) if measured.any() else 0.0,
-            mean_service=float(serv[measured].mean()) if measured.any() else 0.0,
-            max_queue_depth=tmax_depth[ti],
-            op_counts=counts, extras=extras,
+        results.append(_result(
+            run, ti, duration=total, warmup=warmup, name=program.name,
+            scheme=db.scheme, arrival=ten.arrival.name, extras=extras,
             tenant=ten.name, drift=program.name, phases=phase_rows,
-            n_completed=int(completed.sum()), dropped=int(drp.sum()),
+            n_completed=sum(p["n_completed"] for p in phase_rows),
+            dropped=sum(p["n_dropped"] for p in phase_rows),
             drain_violations=violations))
     return results
 

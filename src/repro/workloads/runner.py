@@ -16,17 +16,24 @@ This module adds:
   day-shaped profile), ``FlashCrowdArrivals`` (steady base rate with a
   sudden spike that decays exponentially — news-event traffic), all
   generating arrival timestamps in virtual seconds from a seeded RNG.
-* ``run_open_loop``: arrivals enqueue ops; a bounded server pool (modelling
-  the store's request threads) services the queue.  Per-op accounting
-  splits total latency into *queueing delay* (arrival -> service start)
-  and *service time* (start -> completion), with a warm-up window excluded
-  from statistics and a virtual-time limit on the arrival stream.
+* One open-loop engine (``_serve``): the arrivals of one or more sources
+  merge into one dispatcher that enqueues ops; a bounded server pool
+  (modelling the store's request threads) services the queue, with
+  batched point reads, admission, fault windows, crash/recovery and
+  departure drops as the front end asks.  Per-op accounting
+  (``_result``) splits total latency into *queueing delay* (arrival ->
+  service start) and *service time* (start -> completion), with a warm-up
+  window excluded from statistics and a virtual-time limit on the
+  arrival stream.  Three front ends run it:
+* ``run_open_loop``: one arrival stream.
 * ``run_multi_tenant``: N named tenants (``TenantSpec``), each with its own
   workload, arrival process, and seeded op stream, share one ``DB`` and one
   bounded server pool.  The same queueing/service decomposition is reported
   *per tenant*, and each arrival passes through the store's admission
   controller (``repro.core.middleware.AdmissionController``) so shedding /
   delaying policies can protect an SLO tenant from a misbehaving neighbour.
+* ``repro.workloads.drift.run_drift``: phase-programmed tenants, one
+  source per (tenant, live phase), with per-phase rows.
 * ``ScenarioMatrix``: sweeps (scheme x workload x arrival x SSD-zone
   budget) — or, in multi-tenant mode, (scheme x tenant-mix x admission
   policy x SSD-zone budget) — from a declarative spec, loads a fresh store
@@ -34,8 +41,9 @@ This module adds:
 
 Op semantics are shared with the closed-loop runner via ``OpStream`` —
 placement/migration/caching schemes see byte-identical request streams,
-and a single-tenant run under policy ``none`` is event-for-event identical
-to ``run_open_loop`` (asserted by ``tests/test_multitenant.py``).
+and, since every front end runs the one engine, a single-tenant run under
+policy ``none`` is event-for-event identical to ``run_open_loop``
+(asserted by ``tests/test_multitenant.py``).
 """
 from __future__ import annotations
 
@@ -50,8 +58,8 @@ import numpy as np
 from ..core.middleware import (DELAY, REJECT, AdmissionConfig,
                                AdmissionController)
 from ..zoned.faults import FaultInjector, FaultSpec
-from .ycsb import (OP_NAMES, READ, OpStream, WorkloadSpec, YCSB, _pct,
-                   collect_extras, run_load)
+from .ycsb import (READ, OpStream, WorkloadSpec, YCSB, _pct, collect_extras,
+                   run_load)
 
 
 # ======================================================================
@@ -228,10 +236,12 @@ class FlashCrowdArrivals(ArrivalProcess):
 class OpenLoopResult:
     """Result of one open-loop (sub-)run with queueing/service decomposition.
 
-    One instance describes either a whole single-stream run
-    (``run_open_loop``) or one tenant's slice of a multi-tenant run
-    (``run_multi_tenant``); serialized by :meth:`to_json` it is exactly one
-    row of ``results/storage/scenarios.json``.  Row schema:
+    One instance describes one group of the open-loop engine's ops: a
+    whole single-stream run (``run_open_loop``), one tenant's slice of a
+    multi-tenant run (``run_multi_tenant``) or one tenant of a drift
+    program (``run_drift``); the engine's ``_result`` builds every one the
+    same way, and serialized by :meth:`to_json` it is exactly one row of
+    ``results/storage/scenarios.json``.  Row schema:
 
     ``workload``        workload (``WorkloadSpec``) name, e.g. ``"A"``.
     ``scheme``          placement scheme (``repro.lsm.db.SCHEMES``).
@@ -460,6 +470,330 @@ def _mean(arr: np.ndarray) -> float:
     return float(arr.mean()) if len(arr) else 0.0
 
 
+@dataclass
+class _Source:
+    """One arrival stream of the engine: sorted arrival offsets (virtual
+    seconds from run start), the ``OpStream`` its ops come from, and the
+    group (tenant) its ops count under.  The admission controller and the
+    control plane know the group by ``stream.tenant``.  ``phase`` is the
+    drift phase of a ``run_drift`` slice (0 elsewhere)."""
+
+    group: int
+    rel: np.ndarray
+    stream: OpStream
+    phase: int = 0
+
+
+@dataclass
+class _Run:
+    """What one engine run hands its front end.  The per-source arrays
+    hold each op's arrival, service start and completion (NaN = never),
+    and whether it was dropped (shed by admission, or still queued at a
+    departure)."""
+
+    sources: Sequence[_Source]
+    t0: float
+    busy_span: float
+    arrive: List[np.ndarray]
+    start: List[np.ndarray]
+    done: List[np.ndarray]
+    dropped: List[np.ndarray]
+    max_depth: int                  # peak of the whole queue
+    group_depth: List[int]          # peak of each group's share of it
+    crash: Optional[Dict[str, float]]   # None unless the faults crash
+    lost: List[int]                 # per group, whole-store crash only
+    refused: List[int]
+
+
+def _serve(db, sources: Sequence[_Source], n_groups: int, *,
+           duration: float, drain: bool, max_concurrency: int,
+           read_batch: int = 1, faults: Optional[FaultSpec] = None,
+           ctrl: Optional[AdmissionController] = None, control=None,
+           departures: Sequence[Tuple[float, frozenset]] = ()) -> _Run:
+    """The open-loop engine under ``run_open_loop``, ``run_multi_tenant``
+    and ``repro.workloads.drift.run_drift`` (their docstrings give the
+    semantics).  The sources' arrivals merge by one stable sort (ties keep
+    source order; one sorted source stays as it is) into one dispatcher,
+    one queue and one pool of ``max_concurrency`` servers; batched reads
+    take point READs of one source only.  ``ctrl`` (an
+    ``AdmissionController``) decides every arrival; ``control`` (a
+    ``ControlPlane``) observes every completion and restarts after a
+    crash; ``faults`` arms the device windows and the crash controller;
+    ``departures`` lists ``(offset, source indices)`` whose queued ops are
+    dropped at that offset.
+    """
+    sim = db.sim
+    m_at = (np.concatenate([s.rel for s in sources]) if sources
+            else np.empty(0, np.float64))
+    m_si = np.repeat(np.arange(len(sources)), [len(s.rel) for s in sources])
+    m_i = (np.concatenate([np.arange(len(s.rel)) for s in sources])
+           if sources else np.empty(0, np.int64))
+    order = np.argsort(m_at, kind="stable")
+    m_at, m_si, m_i = (m_at[order].tolist(), m_si[order].tolist(),
+                       m_i[order].tolist())
+    m = len(m_at)
+    group = [s.group for s in sources]
+
+    t0 = sim.now
+    arrive = [np.full(len(s.rel), np.nan) for s in sources]
+    start = [np.full(len(s.rel), np.nan) for s in sources]
+    done = [np.full(len(s.rel), np.nan) for s in sources]
+    dropped = [np.zeros(len(s.rel), bool) for s in sources]
+    queue: deque = deque()                # (source, op) pairs
+    idle: List = []                       # events of parked servers
+    depth = [0] * n_groups                # per-group ops in queue
+    group_depth = [0] * n_groups
+    state = {"closed": False, "max_depth": 0, "dispatched": False,
+             "holding": 0, "next": 0}
+    crashing = faults is not None and faults.crash_at is not None
+    crash: Optional[Dict[str, float]] = {} if crashing else None
+    lost = [0] * n_groups
+    refused = [0] * n_groups
+    if ctrl is not None:
+        ctrl.queue_gauge = lambda: len(queue)
+
+    def _enqueue(si: int, i: int) -> None:
+        queue.append((si, i))
+        g = group[si]
+        depth[g] += 1
+        if depth[g] > group_depth[g]:
+            group_depth[g] = depth[g]
+        if len(queue) > state["max_depth"]:
+            state["max_depth"] = len(queue)
+        if idle:
+            idle.pop().succeed()
+
+    def _maybe_close() -> None:
+        # servers may only exit once arrivals AND held ops are exhausted
+        if state["dispatched"] and state["holding"] == 0 \
+                and not state["closed"]:
+            state["closed"] = True
+            while idle:
+                idle.pop().succeed()
+
+    def held(si: int, i: int):
+        yield from ctrl.hold(sources[si].stream.tenant)
+        state["holding"] -= 1
+        _enqueue(si, i)
+        _maybe_close()
+
+    def dispatcher():
+        # cursor-based (not `for j in range(m)`) so the post-crash
+        # respawn resumes the merged stream where the outage left it
+        while state["next"] < m:
+            j = state["next"]
+            at = t0 + m_at[j]
+            if at > sim.now:
+                yield at - sim.now   # bare-delay: no Event
+            si, i = m_si[j], m_i[j]
+            arrive[si][i] = sim.now
+            state["next"] = j + 1
+            if ctrl is not None:
+                verdict = ctrl.decide(sources[si].stream.tenant)
+                if verdict == REJECT:
+                    dropped[si][i] = True
+                    continue
+                if verdict == DELAY:
+                    state["holding"] += 1
+                    sim.process(held(si, i))
+                    continue
+            _enqueue(si, i)
+        state["dispatched"] = True
+        _maybe_close()
+
+    def server():
+        while True:
+            while not queue:
+                if state["closed"]:
+                    return
+                ev = sim.event()
+                idle.append(ev)
+                yield ev
+            si, i = queue.popleft()
+            g = group[si]
+            depth[g] -= 1
+            stream = sources[si].stream
+            batch = [i]
+            if read_batch > 1 and stream.is_point_read(i):
+                while (queue and len(batch) < read_batch
+                       and queue[0][0] == si
+                       and stream.is_point_read(queue[0][1])):
+                    batch.append(queue.popleft()[1])
+                    depth[g] -= 1
+                work = stream.execute_read_batch(batch)
+            else:
+                work = stream.execute(i)
+            now = sim.now
+            for j in batch:
+                start[si][j] = now
+            yield from work
+            now = sim.now
+            for j in batch:
+                done[si][j] = now
+            if control is not None:
+                for j in batch:
+                    control.observe(stream.tenant, now - arrive[si][j])
+
+    def crash_ctl():
+        at = t0 + faults.crash_at
+        if at > sim.now:
+            yield at - sim.now   # bare-delay: no Event
+        down0 = sim.now
+        shard = faults.crash_shard
+        if shard is None:
+            # everything queued, in flight or held dies with the store
+            for si, g in enumerate(group):
+                lost[g] += int((~np.isnan(arrive[si]) & ~dropped[si]
+                                & np.isnan(done[si])).sum())
+            crash["lost_in_flight"] = sum(lost)
+            db.crash()             # kills dispatcher, servers, held ops
+            queue.clear()
+            idle.clear()
+            depth[:] = [0] * n_groups
+            state["holding"] = 0
+            rec = yield from db.reopen_gen()
+        else:
+            # per-shard power loss (sharded stores): the dispatcher, the
+            # queue and every server not caught mid-op on the crashed
+            # shard keep serving; ops routed to the down shard park at
+            # the router and complete after recovery — only the shard's
+            # own in-flight ops are lost
+            info = db.crash_shard(shard)
+            crash["lost_in_flight"] = int(info["lost_in_flight"])
+            killed = {id(p) for p in info["killed_processes"]}
+            rec = yield from db.reopen_shard_gen(shard)
+        crash.update(rec)
+        crash["downtime"] = sim.now - down0
+        if shard is not None:
+            crash["refused"] = 0
+            # replace exactly the servers that died with the shard
+            for _ in range(sum(1 for p in procs if id(p) in killed)):
+                procs.append(db.submit(server()))
+            return
+        # clients that knocked during the outage were refused: account
+        # their arrival, skip their execution
+        while state["next"] < m and t0 + m_at[state["next"]] <= sim.now:
+            j = state["next"]
+            si, i = m_si[j], m_i[j]
+            arrive[si][i] = t0 + m_at[j]
+            state["next"] = j + 1
+            refused[group[si]] += 1
+        crash["refused"] = sum(refused)
+        # the injector's processes died with the crash: re-arm the fault
+        # windows that have not fired yet on the original schedule
+        FaultInjector(db, faults).arm(t0=t0, after=sim.now - t0)
+        if control is not None:
+            control.start()    # the control loop died with the crash
+        # fresh serving fleet resumes the remaining arrival stream
+        for _ in range(max_concurrency):
+            db.submit(server())
+        db.submit(dispatcher())
+
+    def reaper(at_rel: float, srcs: frozenset):
+        # departure boundary: cancel the departing sources' queued (not
+        # yet started) ops; in-service ops drain
+        at = t0 + at_rel
+        if at > sim.now:
+            yield at - sim.now   # bare-delay: no Event
+        kept = deque()
+        while queue:
+            si, i = queue.popleft()
+            if si in srcs:
+                dropped[si][i] = True
+                depth[group[si]] -= 1
+            else:
+                kept.append((si, i))
+        queue.extend(kept)
+
+    procs = [db.submit(server()) for _ in range(max_concurrency)]
+    procs.append(db.submit(dispatcher()))
+    if faults is not None:
+        FaultInjector(db, faults).arm()
+        if crashing:
+            sim.process(crash_ctl())
+    for at_rel, srcs in departures:
+        procs.append(sim.process(reaper(at_rel, srcs)))
+    if drain:
+        if crashing:
+            # the first fleet dies at the crash, so its completion events
+            # never fire: drive the run to global quiescence instead
+            sim.run()
+        else:
+            for p in procs:
+                sim.run_until(p)
+    else:
+        # hard time limit: stop at the end of the arrival window; ops
+        # still queued, held or in flight are left out of the statistics
+        db.run_for(t0 + duration - sim.now)
+    busy_span = max(sim.now - t0, 1e-12)
+    if crashing:
+        # global quiescence includes background compaction settling after
+        # the last op: clamp the busy span to the last completion so
+        # throughput stays comparable with runs that stop there
+        last = max((float(d[~np.isnan(d)].max())
+                    for d in done if (~np.isnan(d)).any()),
+                   default=sim.now)
+        busy_span = max(last - t0, 1e-12)
+    if ctrl is not None:
+        # this run's queue is dead: later submits must not read it
+        ctrl.queue_gauge = None
+    return _Run(sources, t0, busy_span, arrive, start, done, dropped,
+                state["max_depth"], group_depth, crash, lost, refused)
+
+
+def _result(run: _Run, g: int, *, duration: float, warmup: float,
+            faults: Optional[FaultSpec] = None,
+            crash: Optional[Dict[str, float]] = None,
+            **fields) -> OpenLoopResult:
+    """Group ``g``'s ``OpenLoopResult``: its sources' ops in source order,
+    those that arrived before ``warmup`` left out of the statistics;
+    ``fields`` are the front end's own columns."""
+    mine = [si for si, s in enumerate(run.sources) if s.group == g]
+    arr, st, dn, drp = (np.concatenate([a[si] for si in mine])
+                        for a in (run.arrive, run.start, run.done,
+                                  run.dropped))
+    codes = np.concatenate([run.sources[si].stream.ops.codes
+                            for si in mine])
+    counts: Dict[str, int] = {}
+    for si in mine:
+        for op, c in run.sources[si].stream.counts.items():
+            counts[op] = counts.get(op, 0) + c
+    completed = ~np.isnan(dn)
+    measured = completed & (arr - run.t0 >= warmup)
+    total = dn - arr
+    qdel = st - arr
+    serv = dn - st
+    reads = (codes == READ) & measured
+    if faults is not None:
+        fields["fault"] = faults.label
+        # shed ops are policy, not unavailability
+        fields["availability"] = \
+            float(completed.sum()) / max(len(arr) - int(drp.sum()), 1)
+        if faults.stalls:
+            smask = np.zeros(len(arr), bool)
+            for w in faults.stalls:
+                smask |= ((arr >= run.t0 + w.at)
+                          & (arr < run.t0 + w.at + w.duration))
+            fields["stall_p"] = _pct(total[smask & measured])
+        if crash is not None:
+            fields["crash"] = crash
+            if faults.recovery_slo_s is not None:
+                fields["recovery_slo_s"] = faults.recovery_slo_s
+                fields["recovery_slo_met"] = bool(
+                    crash.get("downtime", float("inf"))
+                    <= faults.recovery_slo_s)
+    return OpenLoopResult(
+        n_arrived=len(arr), n_measured=int(measured.sum()),
+        duration=duration, offered_rate=len(arr) / max(duration, 1e-12),
+        throughput=float(completed.sum()) / run.busy_span,
+        latency_p=_pct(total[measured]), queue_p=_pct(qdel[measured]),
+        service_p=_pct(serv[measured]),
+        read_latency_p=_pct(total[reads]),
+        mean_latency=_mean(total[measured]), mean_queue=_mean(qdel[measured]),
+        mean_service=_mean(serv[measured]),
+        max_queue_depth=run.group_depth[g], op_counts=counts, **fields)
+
+
 def run_open_loop(db, spec: WorkloadSpec, arrival: ArrivalProcess,
                   duration: float, n_keys: int, *, warmup: float = 0.0,
                   max_concurrency: int = 64, seed: int = 1,
@@ -497,176 +831,19 @@ def run_open_loop(db, spec: WorkloadSpec, arrival: ArrivalProcess,
     a fresh server fleet resumes the remaining arrival stream.  The result
     row then carries ``fault`` / ``availability`` / ``stall_p`` / ``crash``
     (see :class:`OpenLoopResult`).
+
+    The run is the open-loop engine (``_serve``) with one source and no
+    admission step.
     """
-    sim = db.sim
     rng = np.random.default_rng(seed + 2)
     rel = arrival.times(rng, duration)
-    n = len(rel)
-    stream = OpStream(db, spec, n_ops=n, n_keys=n_keys, seed=seed)
-    t0 = sim.now
-    arrive = np.full(n, np.nan)
-    start = np.full(n, np.nan)
-    done = np.full(n, np.nan)
-    queue: deque = deque()
-    idle: List = []                       # events of parked servers
-    state = {"closed": False, "max_depth": 0, "next": 0}
-    crash_info: Dict[str, float] = {}
-
-    def dispatcher():
-        while state["next"] < n:
-            i = state["next"]
-            at = t0 + float(rel[i])
-            if at > sim.now:
-                yield at - sim.now   # bare-delay: no Event
-            arrive[i] = sim.now
-            state["next"] = i + 1
-            queue.append(i)
-            if len(queue) > state["max_depth"]:
-                state["max_depth"] = len(queue)
-            if idle:
-                idle.pop().succeed()
-        state["closed"] = True
-        while idle:
-            idle.pop().succeed()
-
-    def server():
-        while True:
-            while not queue:
-                if state["closed"]:
-                    return
-                ev = sim.event()
-                idle.append(ev)
-                yield ev
-            i = queue.popleft()
-            if read_batch > 1 and stream.is_point_read(i):
-                batch = [i]
-                while (queue and len(batch) < read_batch
-                       and stream.is_point_read(queue[0])):
-                    batch.append(queue.popleft())
-                now = sim.now
-                for j in batch:
-                    start[j] = now
-                yield from stream.execute_read_batch(batch)
-                now = sim.now
-                for j in batch:
-                    done[j] = now
-                continue
-            start[i] = sim.now
-            yield from stream.execute(i)
-            done[i] = sim.now
-
-    def crash_ctl():
-        at = t0 + faults.crash_at
-        if at > sim.now:
-            yield at - sim.now   # bare-delay: no Event
-        down0 = sim.now
-        if faults.crash_shard is not None:
-            # per-shard power loss (sharded stores): the dispatcher, the
-            # queue and every server not caught mid-op on the crashed
-            # shard keep serving; ops routed to the down shard park at
-            # the router and complete after recovery — only the shard's
-            # own in-flight ops are lost
-            info = db.crash_shard(faults.crash_shard)
-            crash_info["lost_in_flight"] = int(info["lost_in_flight"])
-            killed = {id(p) for p in info["killed_processes"]}
-            rec = yield from db.reopen_shard_gen(faults.crash_shard)
-            crash_info.update(rec)
-            crash_info["downtime"] = sim.now - down0
-            crash_info["refused"] = 0
-            # replace exactly the servers that died with the shard
-            for _ in range(sum(1 for p in procs if id(p) in killed)):
-                procs.append(db.submit(server()))
-            return
-        crash_info["lost_in_flight"] = \
-            int((~np.isnan(arrive) & np.isnan(done)).sum())
-        db.crash()                 # kills the dispatcher and every server
-        queue.clear()
-        idle.clear()
-        rec = yield from db.reopen_gen()
-        crash_info.update(rec)
-        crash_info["downtime"] = sim.now - down0
-        # clients that knocked during the outage were refused: account
-        # their arrival, skip their execution
-        refused = 0
-        while state["next"] < n and t0 + float(rel[state["next"]]) <= sim.now:
-            i = state["next"]
-            arrive[i] = t0 + float(rel[i])
-            state["next"] = i + 1
-            refused += 1
-        crash_info["refused"] = refused
-        # the injector's processes died with the crash: re-arm the fault
-        # windows that have not fired yet on the original schedule
-        FaultInjector(db, faults).arm(t0=t0, after=sim.now - t0)
-        # fresh serving fleet resumes the remaining arrival stream
-        for _ in range(max_concurrency):
-            db.submit(server())
-        db.submit(dispatcher())
-
-    procs = [db.submit(server()) for _ in range(max_concurrency)]
-    procs.append(db.submit(dispatcher()))
-    crashing = faults is not None and faults.crash_at is not None
-    if faults is not None:
-        FaultInjector(db, faults).arm()
-        if crashing:
-            sim.process(crash_ctl())
-    if drain:
-        if crashing:
-            # the phase-1 processes die at the crash, so their completion
-            # events never fire: drive the run to global quiescence instead
-            sim.run()
-        else:
-            for p in procs:
-                sim.run_until(p)
-    else:
-        # hard time limit: stop at the end of the arrival window; ops still
-        # queued or in flight are excluded from statistics below
-        db.run_for(t0 + duration - sim.now)
-    busy_span = max(sim.now - t0, 1e-12)
-
-    completed = ~np.isnan(done)
-    if crashing and completed.any():
-        # the crash path ran to global quiescence (sim.run()), which
-        # includes background compaction settling after the last op; clamp
-        # the busy span to the last completion so throughput stays
-        # comparable with non-crash cells (run_until stops there)
-        busy_span = max(float(done[completed].max()) - t0, 1e-12)
-    measured = completed & (arrive - t0 >= warmup)
-    total = done - arrive
-    qdel = start - arrive
-    serv = done - start
-    reads = (stream.ops.codes == READ) & measured
-    fault_fields: Dict = {}
-    if faults is not None:
-        fault_fields["fault"] = faults.label
-        fault_fields["availability"] = float(completed.sum()) / max(n, 1)
-        if faults.stalls:
-            smask = np.zeros(n, bool)
-            for w in faults.stalls:
-                smask |= ((arrive >= t0 + w.at)
-                          & (arrive < t0 + w.at + w.duration))
-            fault_fields["stall_p"] = _pct(total[smask & measured])
-        if crashing:
-            fault_fields["crash"] = dict(crash_info)
-            if faults.recovery_slo_s is not None:
-                fault_fields["recovery_slo_s"] = faults.recovery_slo_s
-                fault_fields["recovery_slo_met"] = bool(
-                    crash_info.get("downtime", float("inf"))
-                    <= faults.recovery_slo_s)
-    return OpenLoopResult(
-        name=spec.name, scheme=db.scheme, arrival=arrival.name,
-        n_arrived=n, n_measured=int(measured.sum()), duration=duration,
-        offered_rate=n / max(duration, 1e-12),
-        throughput=float(completed.sum()) / busy_span,
-        latency_p=_pct(total[measured]), queue_p=_pct(qdel[measured]),
-        service_p=_pct(serv[measured]),
-        read_latency_p=_pct(total[reads]),
-        mean_latency=_mean(total[measured]), mean_queue=_mean(qdel[measured]),
-        mean_service=_mean(serv[measured]),
-        max_queue_depth=state["max_depth"],
-        # snapshot: with drain=False the stream keeps mutating its counts
-        # if leftover queued ops execute on a later drain
-        op_counts=dict(stream.counts), extras=collect_extras(db),
-        **fault_fields)
+    stream = OpStream(db, spec, n_ops=len(rel), n_keys=n_keys, seed=seed)
+    run = _serve(db, [_Source(0, rel, stream)], 1, duration=duration,
+                 drain=drain, max_concurrency=max_concurrency,
+                 read_batch=read_batch, faults=faults)
+    return _result(run, 0, duration=duration, warmup=warmup, faults=faults,
+                   crash=run.crash, name=spec.name, scheme=db.scheme,
+                   arrival=arrival.name, extras=collect_extras(db))
 
 
 # ======================================================================
@@ -757,12 +934,12 @@ def run_multi_tenant(db, tenants: Sequence[TenantSpec], duration: float,
     Per-tenant rows then carry ``fault``/``availability``/``stall_p``/
     ``crash`` columns (see :class:`OpenLoopResult`).
 
-    Accounting mirrors ``run_open_loop`` per tenant (queueing vs service
-    decomposition, warm-up exclusion, ``drain`` semantics); with one
-    tenant and policy ``none`` the run is event-for-event identical to
-    ``run_open_loop``.
+    The run is the open-loop engine (``_serve``) with one source per
+    tenant and the admission step on, so accounting is ``run_open_loop``'s
+    per tenant (queueing vs service decomposition, warm-up exclusion,
+    ``drain`` semantics); with one tenant and policy ``none`` the run is
+    event-for-event identical to ``run_open_loop``.
     """
-    sim = db.sim
     names = [t.name for t in tenants]
     if len(set(names)) != len(names):
         raise ValueError(f"duplicate tenant names: {names}")
@@ -786,7 +963,7 @@ def run_multi_tenant(db, tenants: Sequence[TenantSpec], duration: float,
     if ctrl.cfg.policy == "feedback":
         from ..obs.control import ControlPlane
         control = ControlPlane(
-            sim, ctrl,
+            db.sim, ctrl,
             targets={t.name: t.slo_p99 for t in tenants
                      if t.protected and t.slo_p99},
             debt_gauge=ctrl.debt_gauge,
@@ -799,169 +976,19 @@ def run_multi_tenant(db, tenants: Sequence[TenantSpec], duration: float,
     # per-tenant seeds: tenant 0 matches run_open_loop's (seed + 2 arrival
     # rng, seed op stream) so the single-tenant differential holds; the
     # 9973 stride keeps tenants' streams decorrelated
-    rels, streams = [], []
+    sources = []
     for ti, t in enumerate(tenants):
         rng = np.random.default_rng(seed + 2 + 9973 * ti)
-        rels.append(t.arrival.times(rng, duration))
-        streams.append(OpStream(db, specs[ti], n_ops=len(rels[ti]),
-                                n_keys=n_keys, seed=seed + 9973 * ti))
+        rel = t.arrival.times(rng, duration)
+        stream = OpStream(db, specs[ti], n_ops=len(rel), n_keys=n_keys,
+                          seed=seed + 9973 * ti)
         # tag writes with the originating tenant so flushed bytes (and
         # hence compaction debt) attribute back to them
-        streams[-1].tenant = t.name
-    m_at = (np.concatenate(rels) if rels else np.empty(0, np.float64))
-    m_ti = np.concatenate([np.full(len(r), ti, np.int64)
-                           for ti, r in enumerate(rels)]) \
-        if rels else np.empty(0, np.int64)
-    m_i = np.concatenate([np.arange(len(r), dtype=np.int64) for r in rels]) \
-        if rels else np.empty(0, np.int64)
-    order = np.argsort(m_at, kind="stable")   # ties: tenant order
-    m_at, m_ti, m_i = m_at[order], m_ti[order], m_i[order]
-    m = len(m_at)
-
-    t0 = sim.now
-    arrive = [np.full(len(r), np.nan) for r in rels]
-    start = [np.full(len(r), np.nan) for r in rels]
-    done = [np.full(len(r), np.nan) for r in rels]
-    shed = [np.zeros(len(r), bool) for r in rels]   # admission-rejected
-    queue: deque = deque()
-    idle: List = []                       # events of parked servers
-    depth = [0] * len(tenants)            # per-tenant ops in queue
-    tmax_depth = [0] * len(tenants)
-    state = {"closed": False, "max_depth": 0, "dispatched": False,
-             "holding": 0, "next": 0}
-    crash_info: Dict[str, float] = {}
-    lost_t = [0] * len(tenants)           # per-tenant crash accounting
-    refused_t = [0] * len(tenants)
-    ctrl.queue_gauge = lambda: len(queue)
-
-    def _enqueue(ti: int, i: int) -> None:
-        queue.append((ti, i))
-        depth[ti] += 1
-        if depth[ti] > tmax_depth[ti]:
-            tmax_depth[ti] = depth[ti]
-        if len(queue) > state["max_depth"]:
-            state["max_depth"] = len(queue)
-        if idle:
-            idle.pop().succeed()
-
-    def _maybe_close() -> None:
-        # servers may only exit once arrivals AND held ops are exhausted
-        if state["dispatched"] and state["holding"] == 0 \
-                and not state["closed"]:
-            state["closed"] = True
-            while idle:
-                idle.pop().succeed()
-
-    def held(ti: int, i: int):
-        yield from ctrl.hold(names[ti])
-        state["holding"] -= 1
-        _enqueue(ti, i)
-        _maybe_close()
-
-    def dispatcher():
-        # cursor-based (not `for j in range(m)`) so the post-crash
-        # respawn resumes the merged stream where the outage left it
-        while state["next"] < m:
-            j = state["next"]
-            at = t0 + float(m_at[j])
-            if at > sim.now:
-                yield at - sim.now   # bare-delay: no Event
-            ti, i = int(m_ti[j]), int(m_i[j])
-            arrive[ti][i] = sim.now
-            state["next"] = j + 1
-            verdict = ctrl.decide(names[ti])
-            if verdict == REJECT:
-                shed[ti][i] = True
-                continue
-            if verdict == DELAY:
-                state["holding"] += 1
-                sim.process(held(ti, i))
-                continue
-            _enqueue(ti, i)
-        state["dispatched"] = True
-        _maybe_close()
-
-    def server():
-        while True:
-            while not queue:
-                if state["closed"]:
-                    return
-                ev = sim.event()
-                idle.append(ev)
-                yield ev
-            ti, i = queue.popleft()
-            depth[ti] -= 1
-            start[ti][i] = sim.now
-            yield from streams[ti].execute(i)
-            done[ti][i] = sim.now
-            if control is not None:
-                control.observe(names[ti], sim.now - arrive[ti][i])
-
-    def crash_ctl():
-        # mirrors run_open_loop's crash controller, with per-tenant
-        # accounting: everything queued, in flight, or admission-held dies
-        # with the store; arrivals during the outage are refused
-        at = t0 + faults.crash_at
-        if at > sim.now:
-            yield at - sim.now   # bare-delay: no Event
-        for ti in range(len(tenants)):
-            lost_t[ti] = int((~np.isnan(arrive[ti]) & ~shed[ti]
-                              & np.isnan(done[ti])).sum())
-        down0 = sim.now
-        db.crash()                 # kills dispatcher, servers, held ops
-        queue.clear()
-        idle.clear()
-        for ti in range(len(tenants)):
-            depth[ti] = 0
-        state["holding"] = 0       # held ops died with their processes
-        rec = yield from db.reopen_gen()
-        crash_info.update(rec)
-        crash_info["downtime"] = sim.now - down0
-        while state["next"] < m and \
-                t0 + float(m_at[state["next"]]) <= sim.now:
-            j = state["next"]
-            ti, i = int(m_ti[j]), int(m_i[j])
-            arrive[ti][i] = t0 + float(m_at[j])
-            state["next"] = j + 1
-            refused_t[ti] += 1
-        crash_info["lost_in_flight"] = sum(lost_t)
-        crash_info["refused"] = sum(refused_t)
-        # re-arm the not-yet-fired fault windows on the original schedule
-        FaultInjector(db, faults).arm(t0=t0, after=sim.now - t0)
-        if control is not None:
-            control.start()    # the AIMD loop died with the crash
-        for _ in range(max_concurrency):
-            db.submit(server())
-        db.submit(dispatcher())
-
-    procs = [db.submit(server()) for _ in range(max_concurrency)]
-    procs.append(db.submit(dispatcher()))
-    crashing = faults is not None and faults.crash_at is not None
-    if faults is not None:
-        FaultInjector(db, faults).arm()
-        if crashing:
-            sim.process(crash_ctl())
-    if drain:
-        if crashing:
-            # phase-1 processes die at the crash and their completion
-            # events never fire: drive to global quiescence instead
-            sim.run()
-        else:
-            for p in procs:
-                sim.run_until(p)
-    else:
-        # hard time limit (see run_open_loop): shed/held/queued ops that
-        # did not complete are excluded from statistics below
-        db.run_for(t0 + duration - sim.now)
-    busy_span = max(sim.now - t0, 1e-12)
-    if crashing:
-        last = max((float(d[~np.isnan(d)].max())
-                    for d in done if (~np.isnan(d)).any()),
-                   default=sim.now)
-        # clamp to the last completion (see run_open_loop's crash path)
-        busy_span = max(last - t0, 1e-12)
-    ctrl.queue_gauge = None   # this run's queue is dead; don't let later
-    # DB.submit calls read pressure off it
+        stream.tenant = t.name
+        sources.append(_Source(ti, rel, stream))
+    run = _serve(db, sources, len(tenants), duration=duration, drain=drain,
+                 max_concurrency=max_concurrency, faults=faults, ctrl=ctrl,
+                 control=control)
     control_summary = None
     if control is not None:
         # snapshot before stop(): stop restores every knob to neutral
@@ -971,68 +998,35 @@ def run_multi_tenant(db, tenants: Sequence[TenantSpec], duration: float,
     extras = collect_extras(db)
     results: List[OpenLoopResult] = []
     for ti, t in enumerate(tenants):
-        arr, st, dn = arrive[ti], start[ti], done[ti]
-        completed = ~np.isnan(dn)
-        measured = completed & (arr - t0 >= warmup)
-        total = dn - arr
-        qdel = st - arr
-        serv = dn - st
-        reads = (streams[ti].ops.codes == READ) & measured
-        throughput = float(completed.sum()) / busy_span
-        latency_p = _pct(total[measured])
+        crash = None
+        if run.crash is not None:
+            # the run's crash columns with this tenant's share of the ops
+            crash = {k: v for k, v in run.crash.items()
+                     if k not in ("lost_in_flight", "refused")}
+            crash.update(lost_in_flight=run.lost[ti],
+                         refused=run.refused[ti])
+        r = _result(run, ti, duration=duration, warmup=warmup,
+                    faults=faults, crash=crash, name=specs[ti].name,
+                    scheme=db.scheme, arrival=t.arrival.name, extras=extras,
+                    tenant=t.name, policy=ctrl.policy_label,
+                    protected=t.protected,
+                    admission=ctrl.admission_summary(t.name),
+                    control=control_summary)
         # SLO-attainment columns: goodput counts only completions within
         # the tenant's sojourn target (== throughput without a target)
-        slo_fields: Dict = {"goodput": throughput}
+        r.goodput = r.throughput
         if t.slo_p99 is not None:
-            within = int((total[completed] <= t.slo_p99).sum())
-            slo_fields["goodput"] = within / busy_span
-            slo_fields["slo_p99"] = t.slo_p99
-            slo_fields["slo_met"] = bool(latency_p["p99"] <= t.slo_p99)
-        fault_fields: Dict = {}
-        if faults is not None:
-            fault_fields["fault"] = faults.label
-            served = len(arr) - int(shed[ti].sum())
-            fault_fields["availability"] = \
-                float(completed.sum()) / max(served, 1)
-            if faults.stalls:
-                smask = np.zeros(len(arr), bool)
-                for w in faults.stalls:
-                    smask |= ((arr >= t0 + w.at)
-                              & (arr < t0 + w.at + w.duration))
-                fault_fields["stall_p"] = _pct(total[smask & measured])
-            if crashing:
-                cd = dict(crash_info)
-                cd["lost_in_flight"] = lost_t[ti]
-                cd["refused"] = refused_t[ti]
-                fault_fields["crash"] = cd
-                if faults.recovery_slo_s is not None:
-                    fault_fields["recovery_slo_s"] = faults.recovery_slo_s
-                    fault_fields["recovery_slo_met"] = bool(
-                        crash_info.get("downtime", float("inf"))
-                        <= faults.recovery_slo_s)
-        results.append(OpenLoopResult(
-            name=specs[ti].name, scheme=db.scheme, arrival=t.arrival.name,
-            n_arrived=len(arr), n_measured=int(measured.sum()),
-            duration=duration,
-            offered_rate=len(arr) / max(duration, 1e-12),
-            throughput=throughput,
-            latency_p=latency_p, queue_p=_pct(qdel[measured]),
-            service_p=_pct(serv[measured]),
-            read_latency_p=_pct(total[reads]),
-            mean_latency=_mean(total[measured]),
-            mean_queue=_mean(qdel[measured]),
-            mean_service=_mean(serv[measured]),
-            max_queue_depth=tmax_depth[ti],
-            op_counts=dict(streams[ti].counts), extras=extras,
-            tenant=t.name, policy=ctrl.policy_label, protected=t.protected,
-            admission=ctrl.admission_summary(t.name),
-            control=control_summary,
-            **slo_fields, **fault_fields))
+            dn = run.done[ti]
+            sojourn = (dn - run.arrive[ti])[~np.isnan(dn)]
+            r.goodput = int((sojourn <= t.slo_p99).sum()) / run.busy_span
+            r.slo_p99 = t.slo_p99
+            r.slo_met = bool(r.latency_p["p99"] <= t.slo_p99)
+        results.append(r)
     return MultiTenantResult(
         scheme=db.scheme, policy=ctrl.policy_label, duration=duration,
-        n_arrived=m,
-        n_completed=sum(int((~np.isnan(d)).sum()) for d in done),
-        max_queue_depth=state["max_depth"], tenants=results, extras=extras)
+        n_arrived=sum(len(s.rel) for s in sources),
+        n_completed=sum(int((~np.isnan(d)).sum()) for d in run.done),
+        max_queue_depth=run.max_depth, tenants=results, extras=extras)
 
 
 # ======================================================================
